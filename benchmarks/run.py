@@ -43,6 +43,9 @@ def main() -> None:
                 f"choose from: {' '.join(KNOWN)}"
             )
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,seconds,derived")
 
     if only is None or "fig4" in only:

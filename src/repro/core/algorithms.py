@@ -38,6 +38,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
+
 from repro.core import baselines as B
 from repro.core.mixing import MixingOps
 from repro.core.pisco import (
@@ -210,7 +213,7 @@ class Algorithm:
         )
         return BoundAlgorithm(
             name=self.name,
-            init=init,
+            init=_owned_init(init),
             gossip_round=gossip,
             global_round=glob,
             schedule=schedule if schedule is not None else
@@ -221,6 +224,28 @@ class Algorithm:
             server_opt=so,
             opt_policy=policy,
         )
+
+
+def _owned_init(init: Callable) -> Callable:
+    """Wrap an algorithm's ``init`` so the state owns each of its buffers:
+    no leaf is one of the caller's ``x0``/``batch0`` arrays or another leaf
+    (``y = g = G^0`` at init).  The scan driver donates the state to each
+    block, and a buffer can be donated only once and only if nothing else
+    still reads it."""
+
+    def init_owned(loss_fn, x0, batch0):
+        state = init(loss_fn, x0, batch0)
+        seen = {id(v) for v in jax.tree.leaves((x0, batch0))}
+
+        def own(v):
+            if id(v) in seen:
+                return jnp.copy(v)
+            seen.add(id(v))
+            return v
+
+        return jax.tree.map(own, state)
+
+    return init_owned
 
 
 _REGISTRY: Dict[str, Algorithm] = {}

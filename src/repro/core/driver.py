@@ -109,7 +109,11 @@ def make_block_fn(bound: BoundAlgorithm, *, jit: bool = True) -> Callable:
     ``flags`` is the pre-drawn bool vector (block,), ``local``/``comm`` carry
     the block's batches with a leading round axis.  When the algorithm uses a
     single round function for both kinds (FedAvg, SCAFFOLD) the ``lax.cond``
-    is elided."""
+    is elided.
+
+    The jitted block donates ``state``: the carry is updated in place, so a
+    block holds one copy of the agent-stacked state instead of two.  The
+    state passed in is consumed; callers keep only the returned one."""
     gossip, glob = bound.gossip_round, bound.global_round
     same = glob is gossip
     net = bound.network
@@ -138,7 +142,7 @@ def make_block_fn(bound: BoundAlgorithm, *, jit: bool = True) -> Callable:
                 body, state, (flags, w_gossip, w_server, local, comm)
             )
 
-    return jax.jit(block_fn) if jit else block_fn
+    return jax.jit(block_fn, donate_argnums=0) if jit else block_fn
 
 
 def dynamic_round_fns(

@@ -39,7 +39,6 @@ from repro.core.topology import (
     TopologyProcess,
     make_topology_process,
 )
-from repro.utils.compat import shard_map
 from repro.utils.pytree import (
     tree_agent_krum,
     tree_agent_masked_mean,
@@ -473,11 +472,12 @@ def collective_global_mixing(
 
             return jax.tree.map(leaf, local_tree)
 
-        return shard_map(
+        return jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(spec_tree,),
             out_specs=spec_tree,
+            check_vma=False,
         )(tree)
 
     return MixingOps(
@@ -540,11 +540,12 @@ def collective_shift_mixing(
 
             return jax.tree.map(leaf, local_tree)
 
-        return shard_map(
+        return jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(spec_tree,),
             out_specs=spec_tree,
+            check_vma=False,
         )(tree)
 
     g = collective_global_mixing(mesh, agent_axes, spec_tree)
@@ -591,11 +592,12 @@ def collective_dense_mixing(
 
             return jax.tree.map(leaf, local_tree)
 
-        return shard_map(
+        return jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(spec_tree,),
             out_specs=spec_tree,
+            check_vma=False,
         )(tree)
 
     g = collective_global_mixing(mesh, agent_axes, spec_tree)

@@ -30,8 +30,8 @@ def _flash_kernel(
     k_ref,  # (1, 1, bk, d)
     v_ref,  # (1, 1, bk, d)
     o_ref,  # (1, 1, bq, d)
-    m_scr,  # (bq,) running max
-    l_scr,  # (bq,) running denom
+    m_scr,  # (bq, 1) running max
+    l_scr,  # (bq, 1) running denom
     acc_scr,  # (bq, d) running numerator
     *,
     scale: float,
@@ -65,22 +65,24 @@ def _flash_kernel(
         mask = mask & (k_pos > q_pos - window)
     s = jnp.where(mask, s, NEG_INF)
 
+    # row statistics stay (bq, 1) columns: Mosaic has no layout for a 1-D
+    # vector reshaped against the (bq, bk) tile
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     # guard fully-masked rows (all NEG_INF): exp(NEG_INF - NEG_INF) = 1 junk
     row_live = m_new > NEG_INF / 2
-    p = jnp.where(row_live[:, None], p, 0.0)
+    p = jnp.where(row_live, p, 0.0)
     alpha = jnp.where(row_live, jnp.exp(m_prev - m_new), 1.0)
 
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + p @ v
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + p @ v
     m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0, ...] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0, ...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -125,8 +127,8 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,

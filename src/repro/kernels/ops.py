@@ -1,12 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU backends (this container) and False on
-real TPUs, overridable via REPRO_PALLAS_INTERPRET=0/1.
+``interpret`` defaults to True only when JAX's backend is the CPU; on a TPU
+the kernels always compile through Mosaic.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -19,10 +18,7 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(

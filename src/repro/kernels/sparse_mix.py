@@ -46,7 +46,8 @@ def _pad_edges(
     senders: jnp.ndarray, receivers: jnp.ndarray, edge_w: jnp.ndarray
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, int]:
     """Pad directed-edge arrays to an EDGE_BLOCK multiple and reshape to
-    (n_blocks, EDGE_BLOCK) so a BlockSpec can slice one block per grid step.
+    (n_blocks, 1, EDGE_BLOCK) so a BlockSpec can slice one block per grid
+    step (the block's last two dims, (1, EDGE_BLOCK), are TPU-tileable).
     Padding edges carry weight 0 into row 0 — a no-op contribution."""
     e = int(senders.shape[0])
     ep = max(EDGE_BLOCK, -(-e // EDGE_BLOCK) * EDGE_BLOCK)
@@ -57,9 +58,9 @@ def _pad_edges(
         edge_w = jnp.pad(edge_w, (0, pad))
     nb = ep // EDGE_BLOCK
     return (
-        senders.reshape(nb, EDGE_BLOCK),
-        receivers.reshape(nb, EDGE_BLOCK),
-        edge_w.reshape(nb, EDGE_BLOCK),
+        senders.reshape(nb, 1, EDGE_BLOCK),
+        receivers.reshape(nb, 1, EDGE_BLOCK),
+        edge_w.reshape(nb, 1, EDGE_BLOCK),
         nb,
     )
 
@@ -72,9 +73,9 @@ def _sparse_mix_kernel(x_ref, send_ref, recv_ref, ew_ref, sw_ref, o_ref):
     def _init():
         o_ref[...] = sw_ref[:, :1].astype(jnp.float32) * x
 
-    send = send_ref[0]
-    recv = recv_ref[0]
-    w = ew_ref[0].astype(jnp.float32)
+    send = send_ref[0, 0]
+    recv = recv_ref[0, 0]
+    w = ew_ref[0, 0].astype(jnp.float32)
     contrib = w[:, None] * x[send]
     o_ref[...] += jnp.zeros_like(x).at[recv].add(contrib)
 
@@ -92,9 +93,9 @@ def _sparse_compressed_mix_kernel(
         sw = sw_ref[:, :1].astype(jnp.float32)
         o_ref[...] = x + gamma * (sw - 1.0) * q
 
-    send = send_ref[0]
-    recv = recv_ref[0]
-    w = ew_ref[0].astype(jnp.float32)
+    send = send_ref[0, 0]
+    recv = recv_ref[0, 0]
+    w = ew_ref[0, 0].astype(jnp.float32)
     contrib = w[:, None] * q[send]
     o_ref[...] += gamma * jnp.zeros_like(x).at[recv].add(contrib)
 
@@ -146,9 +147,9 @@ def sparse_mix(
         grid=(dp // cb, nb),
         in_specs=[
             pl.BlockSpec((rows, cb), lambda j, e: (0, j)),
-            pl.BlockSpec((1, EDGE_BLOCK), lambda j, e: (e, 0)),
-            pl.BlockSpec((1, EDGE_BLOCK), lambda j, e: (e, 0)),
-            pl.BlockSpec((1, EDGE_BLOCK), lambda j, e: (e, 0)),
+            pl.BlockSpec((1, 1, EDGE_BLOCK), lambda j, e: (e, 0, 0)),
+            pl.BlockSpec((1, 1, EDGE_BLOCK), lambda j, e: (e, 0, 0)),
+            pl.BlockSpec((1, 1, EDGE_BLOCK), lambda j, e: (e, 0, 0)),
             pl.BlockSpec((rows, LANE), lambda j, e: (0, 0)),
         ],
         out_specs=pl.BlockSpec((rows, cb), lambda j, e: (0, j)),
@@ -190,9 +191,9 @@ def sparse_compressed_mix(
         grid=(dp // cb, nb),
         in_specs=[
             pl.BlockSpec((rows, cb), lambda j, e: (0, j)),
-            pl.BlockSpec((1, EDGE_BLOCK), lambda j, e: (e, 0)),
-            pl.BlockSpec((1, EDGE_BLOCK), lambda j, e: (e, 0)),
-            pl.BlockSpec((1, EDGE_BLOCK), lambda j, e: (e, 0)),
+            pl.BlockSpec((1, 1, EDGE_BLOCK), lambda j, e: (e, 0, 0)),
+            pl.BlockSpec((1, 1, EDGE_BLOCK), lambda j, e: (e, 0, 0)),
+            pl.BlockSpec((1, 1, EDGE_BLOCK), lambda j, e: (e, 0, 0)),
             pl.BlockSpec((rows, LANE), lambda j, e: (0, 0)),
             pl.BlockSpec((rows, LANE), lambda j, e: (0, 0)),
         ],

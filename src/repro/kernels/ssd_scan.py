@@ -32,12 +32,12 @@ NEG_INF = -1e30
 
 
 def _ssd_kernel(
-    x_ref,  # (1, cl, 1, P)
-    dt_ref,  # (1, cl, 1)
-    a_ref,  # (1,)
-    b_ref,  # (1, cl, 1, N)
-    c_ref,  # (1, cl, 1, N)
-    y_ref,  # (1, cl, 1, P)
+    x_ref,  # (1, 1, cl, P)
+    dt_ref,  # (1, 1, 1, cl)
+    adt_ref,  # (1, 1, 1, cl)  dt · A_h
+    b_ref,  # (1, 1, cl, N)
+    c_ref,  # (1, 1, cl, N)
+    y_ref,  # (1, 1, cl, P)
     hfin_ref,  # (1, 1, P, N)
     h_scr,  # (P, N) fp32 carried state
     *,
@@ -50,37 +50,43 @@ def _ssd_kernel(
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (cl, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (cl,)
-    a = a_ref[0].astype(jnp.float32)  # scalar
-    b = b_ref[0, :, 0, :].astype(jnp.float32)  # (cl, N)
-    c = c_ref[0, :, 0, :].astype(jnp.float32)  # (cl, N)
+    x = x_ref[0, 0].astype(jnp.float32)  # (cl, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)  # (1, cl)
+    a_dt = adt_ref[0, 0].astype(jnp.float32)  # (1, cl) negative
+    b = b_ref[0, 0].astype(jnp.float32)  # (cl, N)
+    c = c_ref[0, 0].astype(jnp.float32)  # (cl, N)
 
-    a_dt = dt * a  # (cl,) negative
-    cum = jnp.cumsum(a_dt)  # (cl,)
-
-    # decay matrix S[l, s] = exp(cum_l - cum_s) * dt_s   (l >= s)
-    diff = cum[:, None] - cum[None, :]
     li = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 1)
-    seg = jnp.where(li >= si, diff, NEG_INF)
-    s_mat = jnp.exp(seg) * dt[None, :]
+    causal = li >= si
+    # cumsum and the row -> column moves as masked lane reductions and a
+    # square transpose (no 1-D vectors, which Mosaic cannot lay out)
+    cum = jnp.sum(jnp.where(causal, a_dt, 0.0), axis=1, keepdims=True)  # (cl, 1)
+    dt_col = jnp.sum(jnp.where(li == si, dt, 0.0), axis=1, keepdims=True)
+    total = jnp.sum(a_dt, axis=1, keepdims=True)  # (1, 1) = cum at chunk end
+
+    # decay matrix S[l, s] = exp(cum_l - cum_s) * dt_s   (l >= s)
+    cum_ls = jnp.broadcast_to(cum, (cl, cl))
+    seg = jnp.where(causal, cum_ls - cum_ls.T, NEG_INF)
+    s_mat = jnp.exp(seg) * dt
 
     h_in = h_scr[...]  # (P, N)
+    nt = (((1,), (1,)), ((), ()))  # contract the last dims: A @ B.T
 
-    scores = (c @ b.T) * s_mat  # (cl, cl)
+    scores = jax.lax.dot_general(c, b, nt) * s_mat  # (cl, cl)
     y_diag = scores @ x  # (cl, P)
-    y_off = jnp.exp(cum)[:, None] * (c @ h_in.T)  # (cl, P)
-    y_ref[0, :, 0, :] = (y_diag + y_off).astype(y_ref.dtype)
+    y_off = jnp.exp(cum) * jax.lax.dot_general(c, h_in, nt)  # (cl, P)
+    y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # state update to the chunk boundary
-    w = dt * jnp.exp(cum[-1] - cum)  # (cl,)
-    h_new = jnp.exp(cum[-1]) * h_in + (x * w[:, None]).T @ b  # (P, N)
+    w = dt_col * jnp.exp(total - cum)  # (cl, 1)
+    tn = (((0,), (0,)), ((), ()))  # contract the first dims: A.T @ B
+    h_new = jnp.exp(total) * h_in + jax.lax.dot_general(x * w, b, tn)  # (P, N)
     h_scr[...] = h_new
 
     @pl.when(ci == nc - 1)
     def _finish():
-        hfin_ref[0, 0, ...] = h_new
+        hfin_ref[0, 0] = h_new
 
 
 def ssd_scan_kernel(
@@ -101,27 +107,36 @@ def ssd_scan_kernel(
     assert l % cl == 0, f"seq {l} must divide chunk {cl}"
     nc = l // cl
 
+    # head-major layout: every block's last two dims are a (sequence, lane)
+    # tile, or the per-head row of dt over the sequence
+    x_h = jnp.moveaxis(x, 2, 1)  # (B, H, L, P)
+    dt_h = jnp.moveaxis(dt.astype(jnp.float32), 2, 1)[:, :, None, :]  # (B, H, 1, L)
+    adt_h = dt_h * a.astype(jnp.float32)[None, :, None, None]
+    b_h = jnp.moveaxis(b_mat, 2, 1)  # (B, G, L, N)
+    c_h = jnp.moveaxis(c_mat, 2, 1)
+
     kernel = functools.partial(_ssd_kernel, cl=cl, nc=nc)
-    grid = (bsz, h, nc)
+    seq_row = pl.BlockSpec((1, 1, 1, cl), lambda bi, hi, ci: (bi, hi, 0, ci))
+    grouped = pl.BlockSpec((1, 1, cl, n), lambda bi, hi, ci: (bi, hi // group, ci, 0))
     y, hfin = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bsz, h, nc),
         in_specs=[
-            pl.BlockSpec((1, cl, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, cl, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, cl, 1, n), lambda bi, hi, ci: (bi, ci, hi // group, 0)),
-            pl.BlockSpec((1, cl, 1, n), lambda bi, hi, ci: (bi, ci, hi // group, 0)),
+            pl.BlockSpec((1, 1, cl, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            seq_row,
+            seq_row,
+            grouped,
+            grouped,
         ],
         out_specs=[
-            pl.BlockSpec((1, cl, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, cl, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, l, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, l, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a, b_mat, c_mat)
-    return y, hfin
+    )(x_h, dt_h, adt_h, b_h, c_h)
+    return jnp.moveaxis(y, 1, 2), hfin

@@ -106,26 +106,14 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, *, t_o: int = 1,
             rec["compile_s"] = time.perf_counter() - t1
 
             ma = compiled.memory_analysis()
-            # older jaxlib has no peak_memory_in_bytes; args+outputs+temp
-            # (minus donated/aliased buffers) is the upper-bound proxy there
-            peak = getattr(ma, "peak_memory_in_bytes", None)
-            if peak is None:
-                peak = (
-                    ma.argument_size_in_bytes
-                    + ma.output_size_in_bytes
-                    + ma.temp_size_in_bytes
-                    - ma.alias_size_in_bytes
-                )
             rec["memory"] = {
                 "argument_bytes": int(ma.argument_size_in_bytes),
                 "output_bytes": int(ma.output_size_in_bytes),
                 "temp_bytes": int(ma.temp_size_in_bytes),
-                "peak_bytes": int(peak),
+                "peak_bytes": int(ma.peak_memory_in_bytes),
                 "alias_bytes": int(ma.alias_size_in_bytes),
             }
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # older jaxlib: one dict per device
-                ca = ca[0] if ca else {}
             rec["cost"] = {
                 "flops": float(ca.get("flops", 0.0)),
                 "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -5,11 +5,17 @@ never touches JAX device state (the dry-run must set XLA_FLAGS first).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
 
-from repro.utils.compat import make_mesh
+
+def make_mesh(shape: Tuple[int, ...], axes: Sequence[str]) -> jax.sharding.Mesh:
+    """Auto-axis mesh over the first prod(shape) devices."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

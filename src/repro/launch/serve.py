@@ -26,6 +26,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.models import config_from_dict, get_bundle
+from repro.utils.compile_cache import enable_compile_cache
 from repro.serve import (
     ArrivalProcess,
     ContinuousBatcher,
@@ -90,6 +91,7 @@ def main(argv=None) -> int:
         "line of this JSONL file",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     path = args.ckpt
     if path is None and args.ckpt_dir:

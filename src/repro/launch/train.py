@@ -50,6 +50,7 @@ from repro.data.synthetic import synthetic_lm_tokens
 from repro.models import get_bundle
 from repro.models.rope import mrope_text_positions
 from repro.sim import PROFILE_NAMES, make_time_model, tune
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def make_lm_sampler(cfg, n_agents: int, batch: int, seq: int, t_o: int, seed: int = 0):
@@ -221,6 +222,7 @@ def main(argv=None) -> int:
                     help="capture a jax.profiler trace of training into DIR "
                          "(open in TensorBoard's profile plugin)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     bundle = get_bundle(cfg)
@@ -433,6 +435,9 @@ def main(argv=None) -> int:
         state = jax.tree.unflatten(
             treedef, [jnp.asarray(leaf) for leaf in leaves]
         )
+    # The state owns its buffers; dropping the launcher's copies of the
+    # initial point leaves one agent-stacked state on the device.
+    del params, x0
     from repro.obs import profile_capture
 
     t0 = time.perf_counter()

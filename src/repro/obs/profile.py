@@ -3,9 +3,9 @@
 Two instruments, both safe to leave in production code paths:
 
 * :func:`profile_capture` — context manager around ``jax.profiler.trace``.
-  ``outdir=None`` (the default everywhere) is a strict no-op; any profiler
-  failure (unsupported backend, missing tensorboard plugin) degrades to a
-  warning rather than killing a benchmark run.
+  ``outdir=None`` (the default everywhere) is a strict no-op; a capture that
+  was asked for and cannot start raises, so a run never exits 0 without the
+  trace it was told to write.
 
 * :func:`track_compile_time` — measures seconds spent compiling inside the
   ``with`` body, via ``jax.monitoring``'s event-duration listeners (the
@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import warnings
 from typing import Dict, Iterator, List, Optional
 
 
@@ -90,11 +89,5 @@ def profile_capture(outdir: Optional[str]) -> Iterator[None]:
         return
     import jax
 
-    try:
-        ctx = jax.profiler.trace(outdir)
-    except Exception as e:  # pragma: no cover - backend without profiler
-        warnings.warn(f"jax.profiler.trace unavailable ({e}); not profiling")
-        yield
-        return
-    with ctx:
+    with jax.profiler.trace(outdir):
         yield

@@ -199,6 +199,9 @@ class ServeReport:
         reg = MetricsRegistry(meta=dict(meta or {}))
         reg.counter("serve.requests").inc(len(self.requests))
         reg.counter("serve.tokens").inc(self.total_tokens)
+        reg.histogram("serve.request_tokens").observe_many(
+            len(r.tokens) for r in self.requests
+        )
         reg.gauge("serve.tokens_per_s").set(self.tokens_per_s)
         reg.gauge("serve.p50_s").set(self.p50_s)
         reg.gauge("serve.p99_s").set(self.p99_s)

@@ -28,7 +28,7 @@ _SCRIPT = textwrap.dedent(
     from repro.launch.steps import gossip_matrix, mesh_gossip_shifts
     from repro.utils.pytree import tree_agent_mean, tree_agent_mix
 
-    from repro.utils.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((8,), ("agents",))
     n = 8

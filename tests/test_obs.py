@@ -422,9 +422,15 @@ def test_profile_capture_noop_and_real(tmp_path):
     out = tmp_path / "prof"
     with profile_capture(str(out)):
         jnp.arange(4.0).sum().block_until_ready()
-    # degrades to a warning when the profiler is unavailable; when it works
-    # the trace directory exists
-    assert not out.exists() or any(out.rglob("*"))
+    assert any(out.rglob("*.xplane.pb"))
+
+
+def test_profile_capture_that_cannot_start_raises(tmp_path):
+    # one profiler session per process: the nested capture cannot start
+    with profile_capture(str(tmp_path / "outer")):
+        with pytest.raises(RuntimeError):
+            with profile_capture(str(tmp_path / "inner")):
+                pass
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,10 @@
 """End-to-end behaviour tests: the launchers and the paper's headline
 phenomena on small problems."""
 import os
+import shutil
 import subprocess
 import sys
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -16,12 +18,60 @@ from repro.core import (
     replicate_params,
     run_training,
 )
+from repro.utils.compile_cache import enable_compile_cache
+
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 def _env():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the launchers turn on the persistent compile cache; tests keep it off
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return env
+
+
+def test_compile_cache_honours_env_var(monkeypatch, tmp_path):
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_dir_in_checkout(monkeypatch):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        placed = enable_compile_cache()
+        assert placed == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == placed
+        assert enable_compile_cache() == placed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+    assert ".jax_cache/" in (REPO / ".gitignore").read_text().split()
+
+
+@pytest.mark.parametrize("where", ["checkout", "alone"])
+def test_chip_smoke_refuses_without_tpu(tmp_path, where):
+    script = REPO / "chip_smoke.py"
+    if where == "alone":
+        script = Path(shutil.copy(script, tmp_path / "chip_smoke.py"))
+    env = _env()
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, str(script)], cwd=script.parent, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "FAIL" in proc.stderr
 
 
 @pytest.mark.slow

@@ -58,6 +58,25 @@ PyTree = Any
 # loss_fn(params, batch) -> scalar loss for ONE agent.
 LossFn = Callable[[PyTree, Any], jnp.ndarray]
 
+# Named scopes of the round (``jax.named_scope``): they set only the
+# ``op_name`` metadata of the HLO, so a profiler trace attributes device
+# time to the local phase (3a-3c), the communication step (4a-4c, the mix
+# excluded), each W^k mix, and the round's metrics.
+SCOPE_LOCAL = "pisco.local"
+SCOPE_COMM = "pisco.comm"
+SCOPE_MIX = "mix"
+SCOPE_METRICS = "pisco.metrics"
+
+
+def _scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` traced under the named scope ``name``."""
+
+    def call(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    return call
+
 
 @dataclasses.dataclass(frozen=True)
 class PiscoConfig:
@@ -154,10 +173,11 @@ def _local_phase(
         y = tree_add(y, tree_sub(g_new, g))  # (3c)
         return (x, y, g_new), jnp.mean(loss)
 
-    (x_to, y_to, g_to), losses = jax.lax.scan(
-        step, (state.x, state.y, state.g), local_batches
-    )
-    return x_to, y_to, g_to, jnp.mean(losses)
+    with jax.named_scope(SCOPE_LOCAL):
+        (x_to, y_to, g_to), losses = jax.lax.scan(
+            step, (state.x, state.y, state.g), local_batches
+        )
+        return x_to, y_to, g_to, jnp.mean(losses)
 
 
 def _local_phase_rule(
@@ -178,10 +198,11 @@ def _local_phase_rule(
         y = tree_add(y, tree_sub(g_new, g))  # (3c)
         return (x, y, g_new, opt), jnp.mean(loss)
 
-    (x_to, y_to, g_to, opt), losses = jax.lax.scan(
-        step, (state.x, state.y, state.g, opt0), local_batches
-    )
-    return x_to, y_to, g_to, opt, jnp.mean(losses)
+    with jax.named_scope(SCOPE_LOCAL):
+        (x_to, y_to, g_to, opt), losses = jax.lax.scan(
+            step, (state.x, state.y, state.g, opt0), local_batches
+        )
+        return x_to, y_to, g_to, opt, jnp.mean(losses)
 
 
 def _consensus_error(x: PyTree) -> jnp.ndarray:
@@ -197,12 +218,13 @@ def _round_metrics(cfg, mean_loss, loss_c, g_new, x_new, compute_metrics):
     if not compute_metrics:
         z = jnp.zeros(())
         return RoundMetrics(z, z, z)
-    gbar = jax.tree.map(lambda v: jnp.mean(v, axis=0), g_new)
-    return RoundMetrics(
-        loss=(mean_loss * cfg.t_o + jnp.mean(loss_c)) / (cfg.t_o + 1),
-        grad_sq_norm=tree_sq_norm(gbar),
-        consensus_err=_consensus_error(x_new) / cfg.n_agents,
-    )
+    with jax.named_scope(SCOPE_METRICS):
+        gbar = jax.tree.map(lambda v: jnp.mean(v, axis=0), g_new)
+        return RoundMetrics(
+            loss=(mean_loss * cfg.t_o + jnp.mean(loss_c)) / (cfg.t_o + 1),
+            grad_sq_norm=tree_sq_norm(gbar),
+            consensus_err=_consensus_error(x_new) / cfg.n_agents,
+        )
 
 
 def make_round_fn(
@@ -245,7 +267,7 @@ def make_round_fn(
       comm_batch:    pytree with leaves (n_agents, ...) — the fresh Z^{k+1}
     """
     stacked_vg = make_stacked_value_and_grad(loss_fn)
-    mix = mixing.global_avg if global_round else mixing.gossip
+    mix = _scoped(SCOPE_MIX, mixing.global_avg if global_round else mixing.gossip)
     compressed = mixing.compression is not None and not global_round and use_ef
     has_rules = local_opt is not None or server_opt is not None
     if has_rules and local_opt is None:
@@ -255,30 +277,31 @@ def make_round_fn(
         x_to, y_to, g_to, mean_loss = _local_phase(
             stacked_vg, state, local_batches, cfg.eta_l
         )
-        # (4a): X^{k+1} = ((1-eta_c) X^k + eta_c (X^{T_o} - eta_l Y^{T_o})) W^k
-        cand = jax.tree.map(
-            lambda xk, xt, yt: (1.0 - cfg.eta_c) * xk + cfg.eta_c * (xt - cfg.eta_l * yt),
-            state.x,
-            x_to,
-            y_to,
-        )
-        ef = getattr(state, "ef", ())
-        if compressed:
-            cg = mixing.compression
-            key, kx, ky = jax.random.split(ef["key"], 3)
-            x_new, res_x = cg(cand, ef["x"], kx)
-            # (4b): fresh-batch gradients at the mixed point
-            loss_c, g_new = stacked_vg(x_new, comm_batch)
-            # (4c) compressed: the difference form preserves mean_i over the
-            # agent axis, so Lemma 1 (mean Y == mean G) survives exactly.
-            y_new, res_y = cg(tree_add(y_to, tree_sub(g_new, g_to)), ef["y"], ky)
-            ef = {"x": res_x, "y": res_y, "key": key}
-        else:
-            x_new = mix(cand)
-            # (4b): fresh-batch gradients at the mixed point
-            loss_c, g_new = stacked_vg(x_new, comm_batch)
-            # (4c): Y^{k+1} = (Y^{T_o} + G^{k+1} - G^{T_o}) W^k
-            y_new = mix(tree_add(y_to, tree_sub(g_new, g_to)))
+        with jax.named_scope(SCOPE_COMM):
+            # (4a): X^{k+1} = ((1-eta_c) X^k + eta_c (X^{T_o} - eta_l Y^{T_o})) W^k
+            cand = jax.tree.map(
+                lambda xk, xt, yt: (1.0 - cfg.eta_c) * xk + cfg.eta_c * (xt - cfg.eta_l * yt),
+                state.x,
+                x_to,
+                y_to,
+            )
+            ef = getattr(state, "ef", ())
+            if compressed:
+                cg = _scoped(SCOPE_MIX, mixing.compression)
+                key, kx, ky = jax.random.split(ef["key"], 3)
+                x_new, res_x = cg(cand, ef["x"], kx)
+                # (4b): fresh-batch gradients at the mixed point
+                loss_c, g_new = stacked_vg(x_new, comm_batch)
+                # (4c) compressed: the difference form preserves mean_i over the
+                # agent axis, so Lemma 1 (mean Y == mean G) survives exactly.
+                y_new, res_y = cg(tree_add(y_to, tree_sub(g_new, g_to)), ef["y"], ky)
+                ef = {"x": res_x, "y": res_y, "key": key}
+            else:
+                x_new = mix(cand)
+                # (4b): fresh-batch gradients at the mixed point
+                loss_c, g_new = stacked_vg(x_new, comm_batch)
+                # (4c): Y^{k+1} = (Y^{T_o} + G^{k+1} - G^{T_o}) W^k
+                y_new = mix(tree_add(y_to, tree_sub(g_new, g_to)))
 
         new_state = PiscoState(
             x=x_new, y=y_new, g=g_new, step=state.step + 1, ef=ef,
@@ -293,38 +316,39 @@ def make_round_fn(
         x_to, y_to, g_to, lopt, mean_loss = _local_phase_rule(
             stacked_vg, state, local_batches, local_opt, lopt
         )
-        # (4a) generalized: one more rule step along the tracker gives the
-        # communicated point; eta_c interpolates against X^k as before.
-        upd, lopt = local_opt.update(y_to, lopt, x_to)
-        half = apply_updates(x_to, upd)
-        cand = jax.tree.map(
-            lambda xk, h: (1.0 - cfg.eta_c) * xk + cfg.eta_c * h,
-            state.x, half,
-        )
-        ef = getattr(state, "ef", ())
-        if compressed:
-            cg = mixing.compression
-            key, kx, ky = jax.random.split(ef["key"], 3)
-            x_new, res_x = cg(cand, ef["x"], kx)
-            loss_c, g_new = stacked_vg(x_new, comm_batch)
-            y_new, res_y = cg(tree_add(y_to, tree_sub(g_new, g_to)), ef["y"], ky)
-            ef = {"x": res_x, "y": res_y, "key": key}
-        else:
-            if global_round and server_opt is not None:
-                # FedOpt server round: descend from the averaged previous
-                # iterate along the round pseudo-gradient (DESIGN.md §10).
-                x_new, sopt = server_step(
-                    server_opt, sopt, mix(state.x), mix(cand)
-                )
+        with jax.named_scope(SCOPE_COMM):
+            # (4a) generalized: one more rule step along the tracker gives the
+            # communicated point; eta_c interpolates against X^k as before.
+            upd, lopt = local_opt.update(y_to, lopt, x_to)
+            half = apply_updates(x_to, upd)
+            cand = jax.tree.map(
+                lambda xk, h: (1.0 - cfg.eta_c) * xk + cfg.eta_c * h,
+                state.x, half,
+            )
+            ef = getattr(state, "ef", ())
+            if compressed:
+                cg = _scoped(SCOPE_MIX, mixing.compression)
+                key, kx, ky = jax.random.split(ef["key"], 3)
+                x_new, res_x = cg(cand, ef["x"], kx)
+                loss_c, g_new = stacked_vg(x_new, comm_batch)
+                y_new, res_y = cg(tree_add(y_to, tree_sub(g_new, g_to)), ef["y"], ky)
+                ef = {"x": res_x, "y": res_y, "key": key}
             else:
-                x_new = mix(cand)
-            loss_c, g_new = stacked_vg(x_new, comm_batch)
-            # (4c) is untouched by the rules: Lemma 1 survives any of them.
-            y_new = mix(tree_add(y_to, tree_sub(g_new, g_to)))
+                if global_round and server_opt is not None:
+                    # FedOpt server round: descend from the averaged previous
+                    # iterate along the round pseudo-gradient (DESIGN.md §10).
+                    x_new, sopt = server_step(
+                        server_opt, sopt, mix(state.x), mix(cand)
+                    )
+                else:
+                    x_new = mix(cand)
+                loss_c, g_new = stacked_vg(x_new, comm_batch)
+                # (4c) is untouched by the rules: Lemma 1 survives any of them.
+                y_new = mix(tree_add(y_to, tree_sub(g_new, g_to)))
 
-        lopt = comm_opt_state(
-            lopt, mix, cfg.n_agents, opt_policy, is_global=global_round
-        )
+            lopt = comm_opt_state(
+                lopt, mix, cfg.n_agents, opt_policy, is_global=global_round
+            )
         new_state = PiscoState(
             x=x_new, y=y_new, g=g_new, step=state.step + 1, ef=ef,
             opt={"local": lopt, "server": sopt},

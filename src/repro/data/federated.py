@@ -17,6 +17,8 @@ from typing import Any, Callable, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
+
 # Domain-separation tags (repro.core.topology idiom): every RNG stream in the
 # data path is keyed by (tag, seed[, round]) so equal seeds can never alias two
 # different draws.  _PARTITION_TAG fixes the historical bug where the iid
@@ -143,18 +145,25 @@ class RoundSampler:
             for r in range(n_rounds)
         ])
 
+    def _nbytes(self, idx: np.ndarray) -> int:
+        """Bytes of the x and y batches gathered at ``idx``."""
+        return idx.size * (self.data.x_train[0, 0].nbytes + self.data.y_train.itemsize)
+
     def __call__(self, round_idx: int):
         a = self.data.n_agents
         idx = self._round_idx(round_idx)[0]
-        xb = np.take_along_axis(
-            self.data.x_train[None],
-            idx.reshape(self.t_o + 1, a, self.b, *([1] * (self.data.x_train.ndim - 2))),
-            axis=2,
-        )
-        yb = np.take_along_axis(self.data.y_train[None], idx, axis=2)
-        xb, yb = jnp.asarray(xb), jnp.asarray(yb)
-        local = (xb[: self.t_o], yb[: self.t_o])
-        comm = (xb[-1], yb[-1])
+        nbytes = self._nbytes(idx)
+        with span("sample.gather", rounds=1, bytes=nbytes):
+            xb = np.take_along_axis(
+                self.data.x_train[None],
+                idx.reshape(self.t_o + 1, a, self.b, *([1] * (self.data.x_train.ndim - 2))),
+                axis=2,
+            )
+            yb = np.take_along_axis(self.data.y_train[None], idx, axis=2)
+        with span("sample.put", rounds=1, bytes=nbytes):
+            xb, yb = jnp.asarray(xb), jnp.asarray(yb)
+            local = (xb[: self.t_o], yb[: self.t_o])
+            comm = (xb[-1], yb[-1])
         return local, comm
 
     def sample_block(self, start: int, stop: int):
@@ -163,19 +172,24 @@ class RoundSampler:
 
         Each round's indices are drawn from that round's own pure stream, so
         a block draw and ``stop - start`` sequential ``__call__``s see
-        identical batches regardless of where block boundaries fall."""
+        identical batches regardless of where block boundaries fall.  The
+        gather and the put are the profiler spans ``repro.sample.gather``
+        and ``repro.sample.put``; the index draw is left out of both."""
         n = stop - start
         a = self.data.n_agents
         idx = self._round_idx(start, n)
-        xb = np.take_along_axis(
-            self.data.x_train[None, None],
-            idx.reshape(
-                n, self.t_o + 1, a, self.b, *([1] * (self.data.x_train.ndim - 2))
-            ),
-            axis=3,
-        )
-        yb = np.take_along_axis(self.data.y_train[None, None], idx, axis=3)
-        xb, yb = jnp.asarray(xb), jnp.asarray(yb)
-        local = (xb[:, : self.t_o], yb[:, : self.t_o])
-        comm = (xb[:, -1], yb[:, -1])
+        nbytes = self._nbytes(idx)
+        with span("sample.gather", rounds=n, bytes=nbytes):
+            xb = np.take_along_axis(
+                self.data.x_train[None, None],
+                idx.reshape(
+                    n, self.t_o + 1, a, self.b, *([1] * (self.data.x_train.ndim - 2))
+                ),
+                axis=3,
+            )
+            yb = np.take_along_axis(self.data.y_train[None, None], idx, axis=3)
+        with span("sample.put", rounds=n, bytes=nbytes):
+            xb, yb = jnp.asarray(xb), jnp.asarray(yb)
+            local = (xb[:, : self.t_o], yb[:, : self.t_o])
+            comm = (xb[:, -1], yb[:, -1])
         return local, comm

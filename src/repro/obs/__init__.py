@@ -4,7 +4,8 @@ and the perf-regression gate.
 Import layering matters here: :mod:`repro.obs.regress` (and this package
 ``__init__``) must stay stdlib-only so the CI regress-gate lane can run
 ``benchmarks/check_regress.py`` on a bare interpreter, and
-:mod:`repro.obs.profile` imports jax lazily inside its context managers.
+:mod:`repro.obs.profile` and :func:`repro.obs.trace.span` import jax lazily
+inside their context managers.
 """
 from repro.obs.export import (
     to_chrome_trace,
@@ -28,7 +29,14 @@ from repro.obs.regress import (
     compare_payloads,
     format_findings,
 )
-from repro.obs.trace import DEFAULT_ROUND_S, ROUND_TRACK, Span, TraceRecorder
+from repro.obs.trace import (
+    DEFAULT_ROUND_S,
+    ROUND_TRACK,
+    SPAN_PREFIX,
+    Span,
+    TraceRecorder,
+    span,
+)
 
 __all__ = [
     "CompileStats",
@@ -41,6 +49,7 @@ __all__ = [
     "MetricGate",
     "MetricsRegistry",
     "ROUND_TRACK",
+    "SPAN_PREFIX",
     "Span",
     "TraceRecorder",
     "bench_key",
@@ -49,6 +58,7 @@ __all__ = [
     "format_findings",
     "profile_capture",
     "read_jsonl",
+    "span",
     "to_chrome_trace",
     "track_compile_time",
     "validate_chrome_trace",

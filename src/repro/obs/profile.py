@@ -7,8 +7,8 @@ Two instruments, both safe to leave in production code paths:
   was asked for and cannot start raises, so a run never exits 0 without the
   trace it was told to write.
 
-* :func:`track_compile_time` — measures seconds spent compiling inside the
-  ``with`` body, via ``jax.monitoring``'s event-duration listeners (the
+* :func:`track_compile_time` — counts the XLA compilations inside the
+  ``with`` body and measures their seconds, via ``jax.monitoring``'s event-duration listeners (the
   channel JAX's own internal telemetry uses; events fire with names like
   ``/jax/core/compile/backend_compile_duration``).  ``jax.monitoring`` has
   no public unregister, so one module-level listener is installed lazily on
@@ -26,9 +26,12 @@ from typing import Dict, Iterator, List, Optional
 
 @dataclasses.dataclass
 class CompileStats:
-    """Compile seconds observed while a ``track_compile_time`` block ran."""
+    """Compilations observed while a ``track_compile_time`` block ran:
+    ``compiles`` backend compiles (a load from the persistent compilation
+    cache counts as one) taking ``seconds`` in all."""
 
     seconds: float = 0.0
+    compiles: int = 0
     events: Dict[str, float] = dataclasses.field(default_factory=dict)
     supported: bool = True
 
@@ -39,6 +42,7 @@ class CompileStats:
         # top-level one for `seconds` and keep the full split in `events`.
         if event.endswith("backend_compile_duration"):
             self.seconds += duration_s
+            self.compiles += 1
 
 
 _ACTIVE: List[CompileStats] = []
@@ -65,8 +69,8 @@ def _ensure_listener() -> bool:
 
 @contextlib.contextmanager
 def track_compile_time() -> Iterator[CompileStats]:
-    """Yield a :class:`CompileStats` accumulating compile seconds spent
-    inside the block.  Zero overhead beyond a listener dict update per
+    """Yield a :class:`CompileStats` counting the compiles inside the block
+    and their seconds.  Zero overhead beyond a listener dict update per
     compile event; nesting attributes each compile to the innermost block."""
     stats = CompileStats(supported=_ensure_listener())
     _ACTIVE.append(stats)

@@ -21,13 +21,16 @@ Two clocks, same discipline as :class:`~repro.core.trainer.History`:
 
 Spans are plain host data; :mod:`repro.obs.export` serializes them to the
 Chrome trace-event format for ``ui.perfetto.dev``.
+
+Real time is not the recorder's: :func:`span` times a host block on the
+profiler's own clock, as a ``repro.<name>`` annotation in the trace that
+``jax.profiler`` (``--profile DIR``) writes beside the device's operations.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
-from typing import Any, Dict, List, Mapping, Optional
+from typing import Any, Dict, Iterator, List, Mapping, Optional
 
 #: Nominal round width (seconds) when no systems model prices the run — the
 #: trace keeps rendering with rounds as fixed-width slots.
@@ -35,6 +38,24 @@ DEFAULT_ROUND_S = 1e-3
 
 #: The driver timeline track: one span per executed communication round.
 ROUND_TRACK = "rounds"
+
+#: Prefix of the program's host spans in a profiler trace.
+SPAN_PREFIX = "repro."
+
+
+@contextlib.contextmanager
+def span(name: str, **counts: int) -> Iterator[None]:
+    """Time a host block as the profiler annotation ``repro.<name>``.
+
+    ``counts`` are the block's work (``rounds``, ``bytes``), kept as the
+    annotation's arguments so a reader of the trace can turn its time into
+    a rate.  With no profiler capture running this is one check inside
+    ``TraceAnnotation``: nothing is stored on the Python side, the profiler
+    holds the spans and writes them when its capture ends."""
+    import jax
+
+    with jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **counts):
+        yield
 
 
 @dataclasses.dataclass
@@ -95,21 +116,6 @@ class TraceRecorder:
 
     def add_instant(self, track: str, name: str, t: float, **args: Any) -> None:
         self.instants.append(Instant(track=track, name=name, t=float(t), args=args))
-
-    @contextlib.contextmanager
-    def host_span(self, name: str, *, track: str = "host", **args: Any):
-        """Time a host-side block (compile, export, ...) with real seconds.
-
-        Host spans live on their own track so real wall time is never
-        interleaved with the simulated round timeline.
-        """
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add_span(
-                track, name, t0, time.perf_counter() - t0, cat="host", **args
-            )
 
     # -- driver timeline ----------------------------------------------------
 

@@ -11,16 +11,22 @@ Pins the contracts of ``repro.obs``:
   protocols × {loop, scan, events} drivers attribute identical bytes and
   simulated seconds to every round span (pisco in the fast lane, the other
   six in the full lane);
+* the profiler's spans (``obs.trace.span``, the sampler's gather and put)
+  and the PISCO round's named scopes, which change only the HLO's
+  ``op_name`` metadata;
 * the metrics registry (counters monotone, histograms quantile-correct,
   JSONL sink round-trips) and the ``History`` / ``ServeReport`` exporters;
 * the perf-regression gate: tolerance kinds, missing-metric semantics,
   manifest-driven artifact pairing, and the end-to-end CLI — which must
   pass a baseline against itself and fail an injected 2× slowdown.
 """
+import contextlib
 import json
 import os
+import re
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +41,7 @@ from repro.core.compression import make_byte_model
 from repro.core.trainer import History
 from repro.obs import (
     GATES,
+    SPAN_PREFIX,
     MetricGate,
     MetricsRegistry,
     TraceRecorder,
@@ -43,6 +50,7 @@ from repro.obs import (
     compare_payloads,
     profile_capture,
     read_jsonl,
+    span,
     to_chrome_trace,
     track_compile_time,
     validate_chrome_trace,
@@ -123,14 +131,6 @@ def test_recorder_clamps_negative_durations():
     assert rec.spans[-1].dur == 0.0
 
 
-def test_recorder_host_span_measures_wall_time():
-    rec = TraceRecorder()
-    with rec.host_span("work", detail=1):
-        pass
-    (span,) = [s for s in rec.spans if s.cat == "host"]
-    assert span.name == "work" and span.dur >= 0.0 and span.args["detail"] == 1
-
-
 def test_recorder_serve_request_lifecycle():
     req = Request(
         rid=7, agent_id=3, prompt=np.zeros(4, np.int32), max_new_tokens=4,
@@ -159,8 +159,7 @@ def test_chrome_export_schema_and_track_order(tmp_path):
     rec.record_agent_round(0, 1, 0.0, 0.25, False, staleness=0)
     rec.record_agent_round(0, 0, 0.0, 0.25, False, staleness=0)
     rec.add_instant("rounds", "eval", 0.25, grad_sq=0.5)
-    with rec.host_span("compile"):
-        pass
+    rec.add_span("host", "compile", 0.0, 1e-3, cat="host")
     obj = write_trace(str(tmp_path / "t.json"), rec)
     validate_chrome_trace(obj)
     reloaded = json.load(open(tmp_path / "t.json"))
@@ -431,6 +430,142 @@ def test_profile_capture_that_cannot_start_raises(tmp_path):
         with pytest.raises(RuntimeError):
             with profile_capture(str(tmp_path / "inner")):
                 pass
+
+
+def test_track_compile_time_counts_compiles():
+    @jax.jit
+    def f(x):
+        return x * 3.0 - 1.0
+
+    x = jnp.arange(5.0)
+    with track_compile_time() as first:
+        f(x).block_until_ready()
+    with track_compile_time() as again:
+        f(x).block_until_ready()
+    if first.supported:
+        assert first.compiles == 1 and first.seconds > 0.0
+        assert again.compiles == 0 and again.seconds == 0.0
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans and the round's named scopes
+# ---------------------------------------------------------------------------
+
+ROUND_SCOPES = ("pisco.local", "pisco.comm", "mix", "pisco.metrics")
+
+
+def _host_events(trace_dir, prefix=SPAN_PREFIX):
+    """``(name, start, end, stats)`` of the host events named ``prefix...``
+    in the one capture under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = Path(trace_dir).glob("plugins/profile/*/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return sorted(
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events if ev.name.startswith(prefix)
+    )
+
+
+def test_span_is_a_noop_outside_a_capture(tmp_path):
+    with span("before", rounds=1, bytes=8) as got:
+        assert got is None
+    with pytest.raises(ValueError):
+        with span("raises"):
+            raise ValueError("propagates")
+    # nothing was kept for a later capture to write
+    with profile_capture(str(tmp_path)):
+        jnp.arange(3.0).sum().block_until_ready()
+    assert _host_events(tmp_path) == []
+
+
+def test_span_nests_and_carries_its_counts(tmp_path):
+    with profile_capture(str(tmp_path)):
+        with span("outer", rounds=4, bytes=1 << 33):
+            with span("inner"):
+                jnp.arange(3.0).sum().block_until_ready()
+    got = {name: (s, e, stats) for name, s, e, stats in _host_events(tmp_path)}
+    assert set(got) == {"repro.outer", "repro.inner"}
+    (o0, o1, outer), (i0, i1, inner) = got["repro.outer"], got["repro.inner"]
+    assert o0 <= i0 <= i1 <= o1
+    assert outer == {"rounds": 4, "bytes": 1 << 33} and inner == {}
+
+
+@pytest.mark.parametrize("block", [True, False], ids=["sample_block", "per_round"])
+def test_round_sampler_spans_the_gather_and_the_put(tmp_path, block):
+    from repro.data.federated import FederatedDataset, RoundSampler
+
+    rng = np.random.default_rng(0)
+    data = FederatedDataset.from_arrays(
+        rng.random((96, 12), np.float32), rng.integers(0, 3, 96).astype(np.int32), 4,
+        test_fraction=0.0)
+    sampler = RoundSampler(data, batch_size=2, t_o=2, seed=1)
+    with profile_capture(str(tmp_path)):
+        local, comm = sampler.sample_block(3, 6) if block else sampler(3)
+    events = sorted(_host_events(tmp_path), key=lambda ev: ev[1])
+    assert [ev[0] for ev in events] == ["repro.sample.gather", "repro.sample.put"]
+    nbytes = sum(a.nbytes for a in (*local, *comm))
+    assert all(ev[3] == {"rounds": 3 if block else 1, "bytes": nbytes} for ev in events)
+    assert events[0][2] <= events[1][1]  # the put starts after the gather ends
+
+
+def _fleet_block_hlo(local_opt=None, agents=16, rounds=4, batch=4):
+    """Optimized HLO of the fleet's scan block: PISCO on a sparse ring,
+    the paper's 784-32-10 MLP, at ``agents``."""
+    from repro.core.algorithms import get_algorithm
+    from repro.core.driver import make_block_fn
+    from repro.core.pisco import replicate_params
+    from repro.models.simple import mlp_init, mlp_loss
+
+    spec = ExperimentSpec.create(
+        algo="pisco", n_agents=agents, t_o=2, eta_l=0.5, eta_c=1.0, p=0.1, seed=0,
+        topology="ring", sparse=True, driver="scan", block_size=rounds)
+    bound = get_algorithm("pisco").bind(
+        mlp_loss, spec.config, spec.make_mixing(), local_opt=local_opt)
+    sds = jax.ShapeDtypeStruct
+
+    def batches(*lead):
+        return (sds((*lead, agents, batch, 784), jnp.float32),
+                sds((*lead, agents, batch), jnp.int32))
+
+    x0 = jax.eval_shape(
+        lambda: replicate_params(mlp_init(jax.random.PRNGKey(0)), agents))
+    state = jax.eval_shape(lambda x, c: bound.init(mlp_loss, x, c), x0, batches())
+    return make_block_fn(bound).lower(
+        state, sds((rounds,), jnp.bool_), batches(rounds, 2), batches(rounds)
+    ).compile().as_text()
+
+
+def _scope_paths(hlo: str):
+    return [n.split(";")[0].split("/") for n in re.findall(r'op_name="([^"]*)"', hlo)]
+
+
+@pytest.mark.parametrize("local_opt", [None, "momentum"], ids=["legacy", "rule"])
+def test_fleet_block_carries_the_round_scopes(local_opt):
+    paths = _scope_paths(_fleet_block_hlo(local_opt))
+    assert set(ROUND_SCOPES) <= {part for path in paths for part in path}
+    # each mix sits inside the communication step; nothing nests in a mix
+    for path in paths:
+        if "mix" in path:
+            assert path[path.index("mix") - 1] == "pisco.comm"
+
+
+def test_round_scopes_change_only_metadata(monkeypatch):
+    scoped = _fleet_block_hlo()
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain = _fleet_block_hlo()
+    assert not {part for path in _scope_paths(plain) for part in path} & set(ROUND_SCOPES)
+
+    def strip(hlo):
+        """The HLO less its metadata: each instruction's ``metadata={...}``
+        and the tables of source files and stack frames it points into."""
+        hlo = re.sub(r", metadata=\{[^}]*\}", "", hlo)
+        tables = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames|\d+ .*)$")
+        return "\n".join(line for line in hlo.splitlines() if not tables.match(line))
+
+    assert strip(scoped) == strip(plain)
+    assert " fusion(" in strip(plain) and " while(" in strip(plain)
 
 
 # ---------------------------------------------------------------------------

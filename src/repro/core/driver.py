@@ -62,9 +62,9 @@ def stack_rounds(per_round: Sequence[PyTree]) -> PyTree:
 
 def sample_block(sampler: Sampler, start: int, stop: int) -> Tuple[PyTree, PyTree]:
     """``(local, comm)`` for rounds ``[start, stop)`` with a leading round
-    axis.  Samplers exposing ``sample_block(start, stop)`` (one gather + one
-    device put, e.g. :class:`repro.data.RoundSampler`) take the fast path;
-    anything else falls back to per-round calls + on-device stacking."""
+    axis.  Samplers exposing ``sample_block(start, stop)`` (one index put +
+    one device gather, e.g. :class:`repro.data.RoundSampler`) take the fast
+    path; anything else falls back to per-round calls + on-device stacking."""
     fast = getattr(sampler, "sample_block", None)
     if fast is not None:
         return fast(start, stop)

@@ -7,13 +7,17 @@ extreme non-IID.  ``partition_iid`` is the shuffled control.
 :class:`RoundSampler` produces exactly what one PISCO round consumes
 (Algorithm 1 uses T_o + 1 fresh minibatches per agent per round):
 ``local_batches`` with leaves shaped (T_o, n_agents, b, ...) and a
-``comm_batch`` with leaves (n_agents, b, ...).
+``comm_batch`` with leaves (n_agents, b, ...).  It draws the minibatch
+indices on the host and gathers the rows on the device, from a copy of the
+training set that stays there (:attr:`FederatedDataset.resident_train`).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -75,6 +79,19 @@ class FederatedDataset:
     def samples_per_agent(self) -> int:
         return self.x_train.shape[1]
 
+    @functools.cached_property
+    def resident_train(self) -> Tuple[jax.Array, jax.Array]:
+        """The training set on the default device, flattened to
+        ``(A * m, ...)`` and ``(A * m,)``: put once per dataset, the first
+        time a sampler gathers from it, and shared by every sampler over
+        it.  ``x_train`` / ``y_train`` stay the host arrays.  Put eagerly even
+        when the first gather is traced (``jax.eval_shape`` of a sampler),
+        so the cached copy is never a tracer."""
+        rows = self.n_agents * self.samples_per_agent
+        with jax.ensure_compile_time_eval():
+            return (jnp.asarray(self.x_train.reshape(rows, *self.x_train.shape[2:])),
+                    jnp.asarray(self.y_train.reshape(rows)))
+
     @classmethod
     def from_arrays(
         cls,
@@ -101,6 +118,26 @@ class FederatedDataset:
                 seed=_derive_seed(_PARTITION_TAG, seed),
             )
         return cls(xs, ys, x[test_idx], y[test_idx])
+
+
+@jax.jit
+def _gather_batches(x: jax.Array, y: jax.Array, idx: jax.Array):
+    """``(local, comm)`` minibatches at ``idx`` from a flattened training
+    set (``FederatedDataset.resident_train``).
+
+    ``idx`` holds per-agent sample indices shaped ``(..., T_o + 1, A, b)``,
+    with or without a leading round axis; agent ``i``'s rows start at
+    ``i * m``.  The local and comm index slices are gathered apart, so no
+    slice or copy of the batches follows.  The indices are in ``[0, m)`` by
+    construction, so the gather skips the bounds mask."""
+    a = idx.shape[-2]
+    rows = idx + (jnp.arange(a, dtype=idx.dtype) * (x.shape[0] // a))[:, None]
+
+    def take(ids):
+        return (x.at[ids].get(mode="promise_in_bounds"),
+                y.at[ids].get(mode="promise_in_bounds"))
+
+    return take(rows[..., :-1, :, :]), take(rows[..., -1, :, :])
 
 
 class RoundSampler:
@@ -149,47 +186,27 @@ class RoundSampler:
         """Bytes of the x and y batches gathered at ``idx``."""
         return idx.size * (self.data.x_train[0, 0].nbytes + self.data.y_train.itemsize)
 
+    def _gather(self, idx: np.ndarray, rounds: int):
+        """Put ``idx`` on the device as int32 and gather its batches from
+        the resident training set: the profiler spans ``repro.sample.put``
+        (``bytes`` of the indices) and ``repro.sample.gather`` (the
+        gather's dispatch, ``bytes`` of the batches it makes)."""
+        x, y = self.data.resident_train
+        idx = idx.astype(np.int32)
+        with span("sample.put", rounds=rounds, bytes=idx.nbytes):
+            idx_dev = jnp.asarray(idx)
+        with span("sample.gather", rounds=rounds, bytes=self._nbytes(idx), on="device"):
+            return _gather_batches(x, y, idx_dev)
+
     def __call__(self, round_idx: int):
-        a = self.data.n_agents
-        idx = self._round_idx(round_idx)[0]
-        nbytes = self._nbytes(idx)
-        with span("sample.gather", rounds=1, bytes=nbytes):
-            xb = np.take_along_axis(
-                self.data.x_train[None],
-                idx.reshape(self.t_o + 1, a, self.b, *([1] * (self.data.x_train.ndim - 2))),
-                axis=2,
-            )
-            yb = np.take_along_axis(self.data.y_train[None], idx, axis=2)
-        with span("sample.put", rounds=1, bytes=nbytes):
-            xb, yb = jnp.asarray(xb), jnp.asarray(yb)
-            local = (xb[: self.t_o], yb[: self.t_o])
-            comm = (xb[-1], yb[-1])
-        return local, comm
+        return self._gather(self._round_idx(round_idx)[0], 1)
 
     def sample_block(self, start: int, stop: int):
         """Batches for rounds ``[start, stop)`` with a leading round axis, in
-        one numpy gather + one device put (the scan driver's fast path).
+        one index put + one device gather (the scan driver's fast path).
 
         Each round's indices are drawn from that round's own pure stream, so
         a block draw and ``stop - start`` sequential ``__call__``s see
         identical batches regardless of where block boundaries fall.  The
-        gather and the put are the profiler spans ``repro.sample.gather``
-        and ``repro.sample.put``; the index draw is left out of both."""
-        n = stop - start
-        a = self.data.n_agents
-        idx = self._round_idx(start, n)
-        nbytes = self._nbytes(idx)
-        with span("sample.gather", rounds=n, bytes=nbytes):
-            xb = np.take_along_axis(
-                self.data.x_train[None, None],
-                idx.reshape(
-                    n, self.t_o + 1, a, self.b, *([1] * (self.data.x_train.ndim - 2))
-                ),
-                axis=3,
-            )
-            yb = np.take_along_axis(self.data.y_train[None, None], idx, axis=3)
-        with span("sample.put", rounds=n, bytes=nbytes):
-            xb, yb = jnp.asarray(xb), jnp.asarray(yb)
-            local = (xb[:, : self.t_o], yb[:, : self.t_o])
-            comm = (xb[:, -1], yb[:, -1])
-        return local, comm
+        index draw stays on the host and is left out of both spans."""
+        return self._gather(self._round_idx(start, stop - start), stop - start)

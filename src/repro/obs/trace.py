@@ -44,14 +44,15 @@ SPAN_PREFIX = "repro."
 
 
 @contextlib.contextmanager
-def span(name: str, **counts: int) -> Iterator[None]:
+def span(name: str, **counts: int | str) -> Iterator[None]:
     """Time a host block as the profiler annotation ``repro.<name>``.
 
-    ``counts`` are the block's work (``rounds``, ``bytes``), kept as the
-    annotation's arguments so a reader of the trace can turn its time into
-    a rate.  With no profiler capture running this is one check inside
-    ``TraceAnnotation``: nothing is stored on the Python side, the profiler
-    holds the spans and writes them when its capture ends."""
+    ``counts`` are the block's work (``rounds``, ``bytes``) and, for a span
+    that only dispatches device work, where that work runs (``on``), kept
+    as the annotation's arguments so a reader of the trace can turn its
+    time into a rate.  With no profiler capture running this is one check
+    inside ``TraceAnnotation``: nothing is stored on the Python side, the
+    profiler holds the spans and writes them when its capture ends."""
     import jax
 
     with jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **counts):

@@ -9,13 +9,23 @@
   domain-separation tag: passing ``seed`` verbatim made the partition
   permutation the *same stream* as the train/test split, correlating which
   samples land on which agent with which samples went to test.
+
+The sampler gathers on the device from a resident copy of the training set;
+its batches are pinned bit for bit against a plain numpy gather here.
 """
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import ExperimentSpec, Experiment
 from repro.data import FederatedDataset, RoundSampler
-from repro.data.federated import _PARTITION_TAG, _derive_seed, partition_iid
+from repro.data import federated
+from repro.data.federated import (
+    _PARTITION_TAG, _SAMPLER_TAG, _derive_seed, partition_iid,
+)
 
 
 def _data(n_agents=4, n=80, d=3, seed=0):
@@ -104,6 +114,103 @@ def test_legacy_stream_reproduces_stateful_sampler():
     idx = ref.integers(0, m, size=(1, 3, a, 4))[0]
     expect = np.take_along_axis(data.y_train[None], idx, axis=2)
     np.testing.assert_array_equal(np.asarray(first[0][1]), expect[:2])
+
+
+# ---------------------------------------------------------------------------
+# The device gather against a numpy gather
+# ---------------------------------------------------------------------------
+
+
+def _typed_data(feat, n_agents=4, m=6, seed=0):
+    """A float32 / int32 training set already split per agent."""
+    rng = np.random.default_rng(seed)
+    x = rng.random((n_agents, m, *feat), np.float32)
+    y = rng.integers(0, 10, (n_agents, m)).astype(np.int32)
+    return FederatedDataset(x, y, x[0, :0], y[0, :0])
+
+
+def _drawn(seed, start, n, shape, m, legacy):
+    """(n, T_o + 1, A, b) indices of rounds ``start .. start + n - 1`` as the
+    sampler documents them: one pure stream per round, or (legacy) one
+    stream for the sampler, read from its first call."""
+    if legacy:
+        return np.random.default_rng(seed).integers(0, m, size=(n, *shape))
+    return np.stack([
+        np.random.default_rng((_SAMPLER_TAG, seed, k % (1 << 63))).integers(0, m, size=shape)
+        for k in range(start, start + n)
+    ])
+
+
+def _numpy_batches(data, idx):
+    """``(local, comm)`` at ``idx`` (..., T_o + 1, A, b), gathered by numpy
+    from each agent's own rows."""
+    agents = np.arange(data.n_agents)[:, None]
+    ax = idx.ndim - 3
+    t_o = idx.shape[ax] - 1
+    xb, yb = data.x_train[agents, idx], data.y_train[agents, idx]
+    local = tuple(np.take(a, np.arange(t_o), axis=ax) for a in (xb, yb))
+    comm = tuple(np.take(a, -1, axis=ax) for a in (xb, yb))
+    return local, comm
+
+
+@pytest.mark.parametrize("feat", [(3,), (4, 3)], ids=["one_axis", "two_axes"])
+@pytest.mark.parametrize("legacy", [False, True], ids=["pure", "legacy"])
+@pytest.mark.parametrize("call", ["block", "round", "probe"])
+def test_device_gather_matches_a_numpy_gather(call, legacy, feat):
+    data = _typed_data(feat)
+    t_o, b, seed = 2, 5, 11
+    s = RoundSampler(data, batch_size=b, t_o=t_o, seed=seed, legacy_stream=legacy)
+    start, n = {"block": (3, 4), "round": (3, 1), "probe": (-1, 1)}[call]
+    got = s.sample_block(start, start + n) if call == "block" else s(start)
+    idx = _drawn(seed, start, n, (t_o + 1, data.n_agents, b),
+                 data.samples_per_agent, legacy)
+    want = _numpy_batches(data, idx if call == "block" else idx[0])
+    assert all(isinstance(a, jax.Array) for a in (*got[0], *got[1]))
+    for g, w in zip(_flat(got), _flat(want)):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+        np.testing.assert_array_equal(g, w)
+
+
+def test_samplers_over_one_dataset_share_one_resident_copy():
+    data = _typed_data((4, 3), n_agents=5, m=7)
+    s1 = RoundSampler(data, batch_size=2, t_o=2, seed=1)
+    s2 = RoundSampler(data, batch_size=3, t_o=1, seed=2)
+    # a first gather under a trace still caches a concrete copy
+    jax.eval_shape(lambda: s1.sample_block(0, 2))
+    x, y = data.resident_train
+    assert not isinstance(x, jax.core.Tracer)
+    s1.sample_block(0, 2)
+    assert (x.shape, y.shape) == ((35, 4, 3), (35,))
+    s2(0)
+    s2.sample_block(0, 3)
+    assert data.resident_train[0] is x and data.resident_train[1] is y
+    assert sum(a.shape == (35, 4, 3) for a in jax.live_arrays()) == 1
+    # the host arrays stay numpy; a replaced dataset puts its own copy
+    assert isinstance(data.x_train, np.ndarray)
+    assert dataclasses.replace(data).resident_train[0] is not x
+
+
+def test_index_put_carries_four_bytes_per_index(monkeypatch):
+    seen = []
+    real = federated.span
+
+    def spy(name, **counts):
+        seen.append((name, counts))
+        return real(name, **counts)
+
+    monkeypatch.setattr(federated, "span", spy)
+    data = _typed_data((3,))
+    s = RoundSampler(data, batch_size=5, t_o=2, seed=3)
+    s.sample_block(0, 4)
+    s(7)
+    n_idx = 3 * data.n_agents * 5  # (t_o + 1) x agents x batch, per round
+    per_index = 4 * 3 + 4  # float32 x of 3 features, int32 y
+    assert seen == [
+        ("sample.put", {"rounds": 4, "bytes": 4 * 4 * n_idx}),
+        ("sample.gather", {"rounds": 4, "bytes": 4 * n_idx * per_index, "on": "device"}),
+        ("sample.put", {"rounds": 1, "bytes": 4 * n_idx}),
+        ("sample.gather", {"rounds": 1, "bytes": n_idx * per_index, "on": "device"}),
+    ]
 
 
 # ---------------------------------------------------------------------------

@@ -504,10 +504,14 @@ def test_round_sampler_spans_the_gather_and_the_put(tmp_path, block):
     with profile_capture(str(tmp_path)):
         local, comm = sampler.sample_block(3, 6) if block else sampler(3)
     events = sorted(_host_events(tmp_path), key=lambda ev: ev[1])
-    assert [ev[0] for ev in events] == ["repro.sample.gather", "repro.sample.put"]
+    # the indices go to the device first, then the gather runs there
+    assert [ev[0] for ev in events] == ["repro.sample.put", "repro.sample.gather"]
+    rounds = 3 if block else 1
+    n_idx = rounds * 3 * data.n_agents * 2  # rounds x (t_o + 1) x agents x batch
     nbytes = sum(a.nbytes for a in (*local, *comm))
-    assert all(ev[3] == {"rounds": 3 if block else 1, "bytes": nbytes} for ev in events)
-    assert events[0][2] <= events[1][1]  # the put starts after the gather ends
+    assert events[0][3] == {"rounds": rounds, "bytes": 4 * n_idx}
+    assert events[1][3] == {"rounds": rounds, "bytes": nbytes, "on": "device"}
+    assert events[0][2] <= events[1][1]  # the gather starts after the put ends
 
 
 def _fleet_block_hlo(local_opt=None, agents=16, rounds=4, batch=4):

@@ -34,6 +34,12 @@ from repro.models.rope import mrope_text_positions, rope_cos_sin, text_positions
 
 PyTree = Any
 
+# Named scopes of the LM's ends (``jax.named_scope``, metadata only): the
+# embedding lookup, and the final norm with the vocabulary projection and
+# the cross-entropy.  The blocks carry their own (``mamba2.*``).
+SCOPE_EMBED = "lm.embed"
+SCOPE_HEAD = "lm.head"
+
 
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
@@ -229,7 +235,8 @@ def lm_forward(
     positions: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full causal forward; returns (logits (B,S,V), moe_aux)."""
-    x = _embed(params, cfg, tokens, prefix_embeds)
+    with jax.named_scope(SCOPE_EMBED):
+        x = _embed(params, cfg, tokens, prefix_embeds)
     b, s, _ = x.shape
     cos_sin = _cos_sin(cfg, positions, b, s)
     head_pat, period_pat, _ = _period_patterns(cfg)
@@ -253,9 +260,10 @@ def lm_forward(
     x, auxs = jax.lax.scan(body, x, params["layers"], unroll=cfg.scan_unroll or 1)
     aux = aux + jnp.sum(auxs)
 
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
+    with jax.named_scope(SCOPE_HEAD):
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = x @ head
     return logits, aux
 
 
@@ -270,7 +278,8 @@ def _remat(cfg: ModelConfig, fn):
 
 def _hidden_states(params: PyTree, cfg: ModelConfig, tokens, prefix_embeds, positions):
     """Forward to the final norm WITHOUT projecting to the vocabulary."""
-    x = _embed(params, cfg, tokens, prefix_embeds)
+    with jax.named_scope(SCOPE_EMBED):
+        x = _embed(params, cfg, tokens, prefix_embeds)
     b, s, _ = x.shape
     cos_sin = _cos_sin(cfg, positions, b, s)
     head_pat, period_pat, _ = _period_patterns(cfg)
@@ -290,7 +299,8 @@ def _hidden_states(params: PyTree, cfg: ModelConfig, tokens, prefix_embeds, posi
     body = _remat(cfg, period_body) if cfg.remat else period_body
     x, auxs = jax.lax.scan(body, x, params["layers"], unroll=cfg.scan_unroll or 1)
     aux = aux + jnp.sum(auxs)
-    return rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps), aux
+    with jax.named_scope(SCOPE_HEAD):
+        return rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps), aux
 
 
 def _chunked_ce(hidden: jnp.ndarray, head: jnp.ndarray, targets: jnp.ndarray, chunk: int):
@@ -336,9 +346,10 @@ def lm_loss(params: PyTree, cfg: ModelConfig, batch: Dict) -> jnp.ndarray:
             params, cfg, tokens,
             batch.get("prefix_embeds"), batch.get("positions"),
         )
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        txt_hidden = hidden[:, -tokens.shape[1] : -1, :]
-        ce = _chunked_ce(txt_hidden, head, tokens[:, 1:], cfg.loss_chunk)
+        with jax.named_scope(SCOPE_HEAD):
+            head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+            txt_hidden = hidden[:, -tokens.shape[1] : -1, :]
+            ce = _chunked_ce(txt_hidden, head, tokens[:, 1:], cfg.loss_chunk)
         return ce + aux
     logits, aux = lm_forward(
         params,
@@ -348,12 +359,13 @@ def lm_loss(params: PyTree, cfg: ModelConfig, batch: Dict) -> jnp.ndarray:
         positions=batch.get("positions"),
     )
     # align: predict token t+1 from position t (text-only tail of the stream)
-    txt_logits = logits[:, -tokens.shape[1] :, :]
-    pred = txt_logits[:, :-1].astype(jnp.float32)
-    tgt = tokens[:, 1:]
-    logz = jax.nn.logsumexp(pred, axis=-1)
-    gold = jnp.take_along_axis(pred, tgt[..., None], axis=-1)[..., 0]
-    ce = jnp.mean(logz - gold)
+    with jax.named_scope(SCOPE_HEAD):
+        txt_logits = logits[:, -tokens.shape[1] :, :]
+        pred = txt_logits[:, :-1].astype(jnp.float32)
+        tgt = tokens[:, 1:]
+        logz = jax.nn.logsumexp(pred, axis=-1)
+        gold = jnp.take_along_axis(pred, tgt[..., None], axis=-1)[..., 0]
+        ce = jnp.mean(logz - gold)
     return ce + aux
 
 

@@ -171,6 +171,31 @@ def test_device_gather_matches_a_numpy_gather(call, legacy, feat):
         np.testing.assert_array_equal(g, w)
 
 
+def test_token_windows_of_two_streams_stay_with_their_agents():
+    """A language model's data: int32 windows of two token streams,
+    labelled by stream and split the paper's way, so each of two agents
+    holds its own stream.  The device gather draws only that agent's
+    windows, bit-identical to a host gather at the same indices."""
+    seq, m, t_o, b, seed = 16, 6, 2, 3, 2**31 + 7
+    streams = [1000 * i + np.arange(m * seq, dtype=np.int32) for i in range(2)]
+    windows = np.concatenate([s.reshape(m, seq) for s in streams])
+    data = FederatedDataset.from_arrays(
+        windows, np.repeat(np.arange(2, dtype=np.int32), m), 2,
+        heterogeneous=True, test_fraction=0.0, seed=seed)
+    assert data.x_train.shape == (2, m, seq) and data.x_train.dtype == np.int32
+    s = RoundSampler(data, batch_size=b, t_o=t_o, seed=seed)
+    local, comm = s.sample_block(0, 3)
+    for tokens, stream in (local, comm):
+        assert tokens.dtype == jnp.int32
+        agent = np.arange(2)[:, None]
+        assert (np.asarray(stream) == agent).all()
+        assert (np.asarray(tokens) // 1000 == agent[..., None]).all()
+    idx = _drawn(seed, 0, 3, (t_o + 1, 2, b), m, False)
+    for g, w in zip(_flat((local, comm)), _flat(_numpy_batches(data, idx))):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+        np.testing.assert_array_equal(g, w)
+
+
 def test_samplers_over_one_dataset_share_one_resident_copy():
     data = _typed_data((4, 3), n_agents=5, m=7)
     s1 = RoundSampler(data, batch_size=2, t_o=2, seed=1)

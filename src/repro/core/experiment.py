@@ -99,7 +99,7 @@ class ExperimentSpec:
     # doubly stochastic sampled-to-sampled averaging); 1.0 => everyone.
     participation: float = 1.0
     # Sparse substrate (DESIGN.md §12): True => edge-list/CSR mixing
-    # (segment_sum gossip, O(n + m) state), False => dense n×n, None (the
+    # (shift or segment_sum gossip, O(n + m) state), False => dense n×n, None (the
     # default, and what every legacy payload deserializes to) => auto — dense
     # for small fleets (the bit-exact reference), sparse above
     # SPARSE_AUTO_MIN_AGENTS.

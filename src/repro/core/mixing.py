@@ -45,6 +45,7 @@ from repro.utils.pytree import (
     tree_agent_mean,
     tree_agent_median,
     tree_agent_mix,
+    tree_agent_mix_shifts,
     tree_agent_mix_sparse,
     tree_agent_trimmed_mean,
 )
@@ -135,6 +136,9 @@ class MixingOps:
     # participation: the drivers pre-draw per-round matrices host-side and
     # thread them through the round functions (see dynamic_dense_mixing).
     network: Optional["NetworkContext"] = None
+    # Sparse gossip's operand form, static per run (see sparse_gossip):
+    # "offsets/K" (K weighted shifts) or "edges"; None for other mixers.
+    form: Optional[str] = None
 
 
 # ---------------------------------------------------------------------------
@@ -324,46 +328,82 @@ def make_network_mixing(
 
 
 # ---------------------------------------------------------------------------
-# Sparse mixers: gossip as a segment_sum over edges, never materializing n×n
+# Sparse mixers: gossip from the edge list, never materializing n×n
 # ---------------------------------------------------------------------------
 
 
 def _directed_arrays(topo: SparseTopology):
-    """Device arrays for the directed expansion of the base edge list: both
-    orientations of each undirected edge, weights duplicated."""
-    e = topo.edges
-    senders = jnp.asarray(
-        np.concatenate([e[:, 0], e[:, 1]]) if len(e) else np.zeros(0, int),
-        dtype=jnp.int32,
-    )
-    receivers = jnp.asarray(
-        np.concatenate([e[:, 1], e[:, 0]]) if len(e) else np.zeros(0, int),
-        dtype=jnp.int32,
-    )
-    return senders, receivers
+    """Host arrays for the directed expansion of the base edge list: both
+    orientations of each undirected edge (the order ``edge_w`` uses)."""
+    e = topo.edges.reshape(-1, 2)
+    return np.concatenate([e[:, 0], e[:, 1]]), np.concatenate([e[:, 1], e[:, 0]])
+
+
+def sparse_gossip(topo: SparseTopology):
+    """Plan sparse gossip on the host; returns ``(form, mix)`` with
+    ``mix(tree, edge_w, self_w)`` for directed edge weights in base order.
+
+    The directed edges group by their K distinct offsets ``d = (receiver -
+    sender) mod n``; edge ``e`` fills slot ``(k_e, receivers[e])`` of a
+    ``(K, n)`` weight table (a canonical edge list has no duplicate directed
+    edge, so each slot holds at most one, and empty slots stay zero).
+
+    ``"offsets/K"``: the graph is banded, so gossip is K weighted static
+    shifts along the agent axis (:func:`tree_agent_mix_shifts`), no gather
+    and no scatter.  Chosen when it moves fewer rows than the edge form,
+    ``(K + 2) n <= 3 (2m) + 2n``: the ring, path and torus take it.
+    ``"edges"``: the gather + ``segment_sum`` edge form
+    (:func:`tree_agent_mix_sparse`) for graphs with many offsets, such as
+    random_regular, star and erdos_renyi.  Host (numpy) ``edge_w`` builds
+    the offset table on the host, so static gossip lowers to shifts alone.
+    """
+    n = topo.n_agents
+    senders, receivers = _directed_arrays(topo)
+    offsets, k_e = np.unique((receivers - senders) % n, return_inverse=True)
+    offsets, k_e = offsets.tolist(), k_e.reshape(-1)
+    if (len(offsets) + 2) * n > 3 * len(senders) + 2 * n:
+        senders = jnp.asarray(senders, jnp.int32)
+        receivers = jnp.asarray(receivers, jnp.int32)
+
+        def mix_edges(tree, edge_w, self_w):
+            return tree_agent_mix_sparse(tree, senders, receivers, edge_w, self_w, n)
+
+        return "edges", mix_edges
+
+    shape = (len(offsets), n)
+
+    def mix_offsets(tree, edge_w, self_w):
+        if isinstance(edge_w, np.ndarray):
+            table = np.zeros(shape, np.float32)
+            table[k_e, receivers] = edge_w
+        else:
+            table = jnp.zeros(shape, jnp.float32).at[k_e, receivers].set(edge_w)
+        return tree_agent_mix_shifts(tree, offsets, table, self_w)
+
+    return f"offsets/{len(offsets)}", mix_offsets
 
 
 def sparse_mixing(topology: SparseTopology) -> MixingOps:
-    """Static sparse mixers: gossip is ``segment_sum`` over the fixed edge
-    list with precomputed Metropolis weights — O(n + m) state instead of
-    O(n^2), numerically equal to ``dense_mixing`` over the materialized W
-    up to float reassociation."""
-    senders, receivers = _directed_arrays(topology)
-    edge_w = jnp.asarray(
-        np.concatenate([topology.edge_weight, topology.edge_weight]),
-        dtype=jnp.float32,
+    """Static sparse mixers over the fixed edge list with precomputed
+    Metropolis weights — O(n + m) state instead of O(n^2), numerically equal
+    to ``dense_mixing`` over the materialized W up to float reassociation.
+    Gossip runs in the form :func:`sparse_gossip` picks from the edge list:
+    weighted shifts on a banded graph, gather + ``segment_sum`` otherwise."""
+    form, mix = sparse_gossip(topology)
+    edge_w = np.concatenate([topology.edge_weight, topology.edge_weight]).astype(
+        np.float32
     )
-    self_w = jnp.asarray(topology.self_weight, dtype=jnp.float32)
-    n = topology.n_agents
+    self_w = np.asarray(topology.self_weight, np.float32)
 
     def gossip(tree: PyTree) -> PyTree:
-        return tree_agent_mix_sparse(tree, senders, receivers, edge_w, self_w, n)
+        return mix(tree, edge_w, self_w)
 
     return MixingOps(
         gossip=gossip,
         global_avg=tree_agent_mean,
         name=f"sparse/{topology.name}",
         gossip_edges=topology.n_edges,
+        form=form,
     )
 
 
@@ -392,14 +432,11 @@ def dynamic_sparse_mixing(
             seed=process.seed if participation_seed is None else participation_seed,
         )
     base = process.base
-    senders, receivers = _directed_arrays(base)
-    n = process.n_agents
+    form, mix = sparse_gossip(base)
 
     def gossip(tree: PyTree) -> PyTree:
         ops = slot.gossip_w
-        return tree_agent_mix_sparse(
-            tree, senders, receivers, ops["edge_w"], ops["self_w"], n
-        )
+        return mix(tree, ops["edge_w"], ops["self_w"])
 
     if part is None:
         global_avg = tree_agent_mean
@@ -418,6 +455,7 @@ def dynamic_sparse_mixing(
         network=NetworkContext(
             process=process, slot=slot, participation=part, sparse=True
         ),
+        form=form,
     )
 
 

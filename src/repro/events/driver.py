@@ -39,14 +39,10 @@ from repro.core.driver import (
     record_block,
     sample_block,
 )
-from repro.core.mixing import DynamicWSlot, MixingOps, _directed_arrays
+from repro.core.mixing import DynamicWSlot, MixingOps, sparse_gossip
 from repro.core.topology import make_sparse_topology, make_topology
 from repro.events.clock import EventEngine
-from repro.utils.pytree import (
-    tree_agent_mix,
-    tree_agent_mix_sparse,
-    tree_agent_weighted_mean,
-)
+from repro.utils.pytree import tree_agent_mix, tree_agent_weighted_mean
 
 PyTree = Any
 
@@ -85,13 +81,11 @@ def make_async_mixing(spec: Any) -> MixingOps:
         stopo = make_sparse_topology(
             spec.topology, n, **dict(spec.topology_kwargs)
         )
-        senders, receivers = _directed_arrays(stopo)
+        form, mix = sparse_gossip(stopo)
 
         def gossip(tree: PyTree) -> PyTree:
             ops = slot.gossip_w
-            return tree_agent_mix_sparse(
-                tree, senders, receivers, ops["edge_w"], ops["self_w"], n
-            )
+            return mix(tree, ops["edge_w"], ops["self_w"])
 
         gossip_edges = stopo.n_edges
         base_name = stopo.name
@@ -103,6 +97,7 @@ def make_async_mixing(spec: Any) -> MixingOps:
 
         gossip_edges = int(topo.adj.sum()) // 2
         base_name = topo.name
+        form = None
 
     def global_avg(tree: PyTree) -> PyTree:
         ops = slot.server_w
@@ -114,6 +109,7 @@ def make_async_mixing(spec: Any) -> MixingOps:
         name=f"events/{base_name}",
         gossip_edges=gossip_edges,
         network=EventNetwork(slot, spec.use_sparse),
+        form=form,
     )
     if getattr(spec, "adversary", None) is not None:
         # same wrap order as ExperimentSpec.make_mixing: corruption before

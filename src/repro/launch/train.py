@@ -129,7 +129,7 @@ def main(argv=None) -> int:
     ap.add_argument("--participation", type=float, default=1.0,
                     help="fraction of agents sampled into each server round")
     ap.add_argument("--sparse", action="store_true",
-                    help="edge-list/CSR mixing (segment_sum gossip, "
+                    help="edge-list/CSR mixing (shift or segment_sum gossip, "
                          "O(n+m) state) — required for large fleets; "
                          "default dense n x n (auto-selected by "
                          "ExperimentSpec above 512 agents)")

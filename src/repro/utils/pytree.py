@@ -93,6 +93,8 @@ def tree_agent_mix_sparse(tree, senders, receivers, edge_w, self_w, n_agents):
     each undirected edge with the weight duplicated; per-round edge dropout
     is expressed as zeros in ``edge_w`` (fixed shapes, so scan can thread
     the weights as operands).  Accumulates in float32, like the dense path.
+    ``core.mixing.sparse_gossip`` runs this form only for graphs that are
+    not banded; banded ones take :func:`tree_agent_mix_shifts`.
     """
 
     def mix(x):
@@ -101,6 +103,37 @@ def tree_agent_mix_sparse(tree, senders, receivers, edge_w, self_w, n_agents):
         contrib = edge_w.reshape(edge_w.shape + extra) * xf[senders]
         acc = jax.ops.segment_sum(contrib, receivers, num_segments=n_agents)
         return (self_w.reshape(self_w.shape + extra) * xf + acc).astype(x.dtype)
+
+    return jax.tree.map(mix, tree)
+
+
+def tree_agent_mix_shifts(tree, offsets, weights, self_w):
+    """Sparse gossip over a banded graph as weighted shifts of the agent axis.
+
+    Per leaf ``x`` of shape (n, ...), with ``weights`` of shape (K, n):
+
+        out_i = self_w[i] * x_i + sum_k weights[k, i] * x_{(i - offsets[k]) mod n}
+
+    The offsets are static.  Each leaf is extended once by its wrap-around
+    rows (``xp[j] = x[(j - lo) mod n]``, lo and hi rows at the two ends, as
+    many as the largest shift each way), and each shift is a static slice of
+    the extension, so the leaf mixes in one elementwise pass: no gather and
+    no scatter.  (A ``roll`` per shift would have the TPU compiler write
+    each shifted copy out first.)  Absent edges are zeros in ``weights``.
+    Accumulates in float32, like the edge form.
+    """
+    n = self_w.shape[0]
+    signed = [d - n if 2 * d > n else d for d in offsets]
+    lo, hi = max([0, *signed]), max([0, *(-d for d in signed)])
+
+    def mix(x):
+        xf = x.astype(jnp.float32)
+        extra = (1,) * (x.ndim - 1)
+        xp = jnp.concatenate([xf[n - lo:], xf, xf[:hi]], axis=0)
+        acc = self_w.reshape(self_w.shape + extra) * xf
+        for k, d in enumerate(signed):
+            acc = acc + weights[k].reshape((n,) + extra) * xp[lo - d:lo - d + n]
+        return acc.astype(x.dtype)
 
     return jax.tree.map(mix, tree)
 

@@ -8,6 +8,7 @@ Property tests run through the optional-hypothesis shim (``tests/_hyp.py``),
 so they degrade to deterministic fixed examples when hypothesis is absent.
 """
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,7 @@ from repro.core import (
     sparse_mixing,
     use_sparse_topology,
 )
+from repro.core.mixing import sparse_gossip
 from repro.core.topology import (
     SPARSE_AUTO_MIN_AGENTS,
     metropolis_weights,
@@ -68,7 +70,7 @@ def _random_connected_edges(n, seed, extra_prob=0.3):
 
 
 # ---------------------------------------------------------------------------
-# Property: segment-sum gossip over the edge list == dense W @ X
+# Property: sparse gossip over the edge list == dense W @ X
 # ---------------------------------------------------------------------------
 
 
@@ -100,6 +102,72 @@ def test_sparse_gossip_matches_dense_matrix_product(n, seed, cols):
         np.asarray(out), w.astype(np.float32) @ np.asarray(x),
         rtol=1e-5, atol=1e-6,
     )
+
+
+# ---------------------------------------------------------------------------
+# The offset (banded) form: gossip as weighted shifts along the agent axis
+# ---------------------------------------------------------------------------
+
+_BANDED = [("ring", 2, (3, 4)), ("ring", 64, (5,)), ("path", 64, (2, 3)),
+           ("torus", 64, (4,))]
+
+
+def _directed_weights(topo):
+    e = topo.edges
+    return (np.concatenate([e[:, 0], e[:, 1]]), np.concatenate([e[:, 1], e[:, 0]]),
+            np.concatenate([topo.edge_weight, topo.edge_weight]))
+
+
+@pytest.mark.parametrize("name,n,tail", _BANDED)
+def test_offset_form_matches_dense_matrix_product(name, n, tail):
+    topo = make_sparse_topology(name, n)
+    mixing = sparse_mixing(topo)
+    assert mixing.form.startswith("offsets/")
+    x = np.random.default_rng(n).normal(size=(n,) + tail).astype(np.float32)
+    out = jax.jit(mixing.gossip)({"a": jnp.asarray(x)})["a"]
+    ref = np.tensordot(topo.dense_w().astype(np.float32), x, axes=1)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("name,n,tail", _BANDED)
+def test_offset_form_with_dropped_edges_matches_dense(name, n, tail):
+    """Zeros in a traced ``edge_w`` (as the dynamic and events drivers stage
+    them) leave their slots empty: the result is the dropped-edge W @ x."""
+    topo = make_sparse_topology(name, n)
+    form, mix = sparse_gossip(topo)
+    assert form.startswith("offsets/")
+    senders, receivers, edge_w = _directed_weights(topo)
+    rng = np.random.default_rng(n + 1)
+    keep = np.tile(rng.random(topo.n_edges) < 0.5, 2)
+    edge_w = np.where(keep, edge_w, 0.0).astype(np.float32)
+    self_w = rng.random(n).astype(np.float32)
+    w = np.diag(self_w).astype(np.float64)
+    np.add.at(w, (receivers, senders), edge_w)
+    x = rng.normal(size=(n,) + tail).astype(np.float32)
+    out = jax.jit(mix)(jnp.asarray(x), jnp.asarray(edge_w), jnp.asarray(self_w))
+    ref = np.tensordot(w, x, axes=1)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize(
+    "name,n,form",
+    [("ring", 2, "offsets/1"), ("ring", 64, "offsets/2"), ("path", 64, "offsets/2"),
+     ("torus", 64, "offsets/6"), ("random_regular", 64, "edges"),
+     ("star", 64, "edges"), ("erdos_renyi", 64, "edges")],
+)
+def test_sparse_gossip_form_follows_the_edge_list(name, n, form):
+    topo = make_sparse_topology(name, n)
+    assert sparse_mixing(topo).form == form
+    process = make_topology_process("bernoulli:0.3", topo, seed=0)
+    assert dynamic_sparse_mixing(process).form == form
+
+
+def test_ring_gossip_lowers_without_gather_or_scatter():
+    gossip = sparse_mixing(make_sparse_topology("ring", 64)).gossip
+    x = {"w": jnp.zeros((64, 7, 3)), "b": jnp.zeros((64, 3))}
+    lowered = jax.jit(gossip).lower(x)
+    assert not re.search(r"stablehlo\.(gather|scatter)", lowered.as_text())
+    assert not re.search(r"\b(gather|scatter)\(", lowered.compile().as_text())
 
 
 @settings(max_examples=10, deadline=None)

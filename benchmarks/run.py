@@ -227,7 +227,6 @@ def main() -> None:
         derived = (
             f"mem_savings_n{biggest['n_agents']}={ratio:.0f}x"
             f";per_round_ms={biggest['per_round_s'] * 1e3:.1f}"
-            f";parity_n{payload['parity']['n']}={payload['parity']['ok']}"
         )
         _row("fig_sparse", time.perf_counter() - t0, derived)
 

@@ -22,10 +22,10 @@ Default run, in this one process (a chip belongs to one process at a time):
 
 ``--chips 4`` runs only the agent-sharded collective path: four agents, one
 per chip, mamba2-370m at full width.  Ring gossip (``ppermute``) and server
-averaging (``psum``) from :mod:`repro.core.mixing` against the dense
-``tree_agent_mix`` / ``tree_agent_mean`` on the same sharded inputs, for the
-full-depth parameter tree and for one PISCO round of each kind on the model
-cut to two layers.
+averaging (``psum``) from :mod:`repro.core.mixing` against the single-device
+mixers (edge-list gossip with the mesh ring's weights, ``tree_agent_mean``)
+on the same sharded inputs, for the full-depth parameter tree and for one
+PISCO round of each kind on the model cut to two layers.
 
 The script refuses to run unless JAX's first device is a TPU (``--reduced``
 is the CPU rehearsal at the smoke-size presets).  Earlier lines report
@@ -241,7 +241,7 @@ def serve_phase(reduced: bool, dev) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# four chips: collective mixers vs dense references
+# four chips: collective mixers vs single-device references
 # ---------------------------------------------------------------------------
 
 
@@ -275,23 +275,33 @@ def collective_phase(reduced: bool, n: int) -> dict:
     import dataclasses
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import get_config, get_reduced
-    from repro.core.mixing import MixingOps, collective_shift_mixing
+    from repro.core.mixing import MixingOps, collective_shift_mixing, sparse_gossip
     from repro.core.pisco import PiscoConfig, init_state, make_round_fn
+    from repro.core.topology import make_sparse_topology
     from repro.launch.mesh import make_mesh
     from repro.launch.steps import gossip_matrix, mesh_gossip_shifts
     from repro.models import get_bundle
-    from repro.utils.pytree import tree_agent_mean, tree_agent_mix
+    from repro.utils.pytree import tree_agent_mean
 
     mesh = make_mesh((n,), ("agents",))
     axes = ("agents",)
     shifts = mesh_gossip_shifts(mesh, axes)
     w = gossip_matrix(mesh, axes, shifts)
     print(f"[collective] {n} agents on {n} devices, ring W row 0 = {w[0].tolist()}")
+    # the mesh ring's W as edge weights over the ring's edge list
+    ring = make_sparse_topology("ring", n)
+    _, edge_mix = sparse_gossip(ring)
+    senders = np.concatenate([ring.edges[:, 0], ring.edges[:, 1]])
+    receivers = np.concatenate([ring.edges[:, 1], ring.edges[:, 0]])
+    edge_w = np.asarray(w[receivers, senders], np.float32)
+    self_w = np.asarray(np.diag(w), np.float32)
+
+    def ring_gossip(tree):
+        return edge_mix(tree, edge_w, self_w)
 
     def agent_sharded(bundle, keys):
         sds = jax.eval_shape(jax.vmap(bundle.init), keys)
@@ -308,11 +318,10 @@ def collective_phase(reduced: bool, n: int) -> dict:
     x, spec = agent_sharded(bundle, keys)
     _check_one_agent_per_device(x, n, "input")
     ops = collective_shift_mixing(mesh, axes, spec, shifts)
-    wj = jnp.asarray(w, jnp.float32)
     t0 = time.perf_counter()
     mixed = jax.jit(ops.gossip)(x)
     _check_one_agent_per_device(mixed, n, "collective gossip output")
-    err_w = _rel_err(mixed, jax.jit(lambda t: tree_agent_mix(t, wj))(x))
+    err_w = _rel_err(mixed, jax.jit(ring_gossip)(x))
     avg = jax.jit(ops.global_avg)(x)
     _check_one_agent_per_device(avg, n, "collective server output")
     err_j = _rel_err(avg, jax.jit(tree_agent_mean)(x))
@@ -320,12 +329,12 @@ def collective_phase(reduced: bool, n: int) -> dict:
           f"rel err {err_j:.2e} (tol {MIX_RTOL:g}), "
           f"{time.perf_counter() - t0:.2f} s", flush=True)
     if not (err_w <= MIX_RTOL and err_j <= MIX_RTOL):
-        fail(f"collective mixers disagree with dense: W {err_w}, J {err_j}")
+        fail(f"collective mixers disagree with edge-list: W {err_w}, J {err_j}")
     out.update(mix_gossip_rel_err=err_w, mix_server_rel_err=err_j)
     del x, mixed, avg
 
     # (b) one PISCO round of each kind on the full-width model cut to two
-    # layers: collective mixers vs dense mixing of the same sharded state
+    # layers: collective mixers vs edge-list mixing of the same sharded state
     cfg2 = dataclasses.replace(cfg, n_layers=2)
     bundle2 = get_bundle(cfg2)
     x2, spec2 = agent_sharded(bundle2, keys)
@@ -339,24 +348,22 @@ def collective_phase(reduced: bool, n: int) -> dict:
         NamedSharding(mesh, P("agents")))}
     pcfg = PiscoConfig(n_agents=n, t_o=1, eta_l=0.05, p=0.5)
     coll = collective_shift_mixing(mesh, axes, spec2, shifts)
-    dense = MixingOps(
-        gossip=lambda t: tree_agent_mix(t, wj), global_avg=tree_agent_mean
-    )
+    single = MixingOps(gossip=ring_gossip, global_avg=tree_agent_mean)
     state = jax.jit(lambda x0, b0: init_state(bundle2.loss, x0, b0))(x2, comm)
     for kind, is_global in (("gossip", False), ("server", True)):
         t0 = time.perf_counter()
         s_c, m_c = jax.jit(make_round_fn(
             bundle2.loss, pcfg, coll, global_round=is_global))(state, local, comm)
         s_d, m_d = jax.jit(make_round_fn(
-            bundle2.loss, pcfg, dense, global_round=is_global))(state, local, comm)
+            bundle2.loss, pcfg, single, global_round=is_global))(state, local, comm)
         _check_one_agent_per_device(s_c.x, n, f"{kind} round state")
         err = max(_rel_err(getattr(s_c, f), getattr(s_d, f)) for f in "xyg")
         lc, ld = float(m_c.loss), float(m_d.loss)
         print(f"[collective] PISCO {kind} round: state rel err {err:.2e} "
-              f"(tol {ROUND_RTOL:g}), loss {lc:.6f} vs dense {ld:.6f}, "
+              f"(tol {ROUND_RTOL:g}), loss {lc:.6f} vs edge-list {ld:.6f}, "
               f"{time.perf_counter() - t0:.2f} s", flush=True)
         if not (math.isfinite(lc) and err <= ROUND_RTOL):
-            fail(f"collective {kind} round disagrees with dense: {err}")
+            fail(f"collective {kind} round disagrees with edge-list: {err}")
         out[f"round_{kind}_rel_err"] = err
     return out
 

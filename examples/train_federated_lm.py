@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_checkpoint
-from repro.core import PiscoConfig, dense_mixing, make_topology, replicate_params
+from repro.core import PiscoConfig, make_sparse_topology, replicate_params, sparse_mixing
 from repro.core.algorithms import get_algorithm
 from repro.core.driver import make_block_fn, predraw_schedule, sample_block
 from repro.core.schedule import CommAccountant
@@ -94,8 +94,7 @@ def main():
     pcfg = PiscoConfig(
         n_agents=args.n_agents, t_o=args.t_o, eta_l=args.eta_l, eta_c=1.0, p=args.p
     )
-    topo = make_topology("ring", args.n_agents)
-    mixing = dense_mixing(topo)
+    mixing = sparse_mixing(make_sparse_topology("ring", args.n_agents))
     # Registry API: one bound algorithm (round fns + Bernoulli(p) schedule +
     # comm profile), one jitted scan over each block of rounds.  The same
     # UpdateRule API that drives the logreg experiments plugs in here — e.g.

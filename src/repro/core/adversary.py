@@ -262,7 +262,6 @@ class AdversarialNetwork:
         self.slot = _CompositeSlot(
             None if base is None else base.slot, self.adv_slot
         )
-        self.sparse = bool(getattr(base, "sparse", False))
 
     def augment(self, w_gossip, start: int, stop: int):
         """Wrap a base gossip operand with the rounds' indices (the events
@@ -313,8 +312,8 @@ def make_adversarial_mixing(
     """Wrap any mixing with fault injection and/or a robust server rule.
 
     * ``gossip``      becomes corrupt-then-mix: Byzantine rows are replaced
-      on the wire, then the base gossip (dense, sparse, dynamic, collective)
-      runs unchanged.  Wrapping happens *before* compression, so under a
+      on the wire, then the base gossip (static, dynamic, collective) runs
+      unchanged.  Wrapping happens *before* compression, so under a
       compressed spec the corruption rides the compressed wire stream.
     * ``global_avg``  becomes corrupt-then-aggregate, where the aggregate is
       the base rule for ``robust_agg="mean"`` or a robust rule (trimmed /

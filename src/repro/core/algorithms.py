@@ -95,7 +95,7 @@ class BoundAlgorithm:
     schedule: Callable[[int], bool]
     comm: CommProfile
     # NetworkContext when the mixing is dynamic (time-varying topology and/or
-    # partial participation): the drivers pre-draw per-round matrices through
+    # partial participation): the drivers pre-draw per-round operands through
     # it and thread them into the round functions.  None => static network,
     # the exact pre-dynamic code path.
     network: Optional[Any] = None

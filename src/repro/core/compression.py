@@ -25,7 +25,7 @@ Three pieces:
   next round, restoring convergence for biased compressors (top-k).
 
 * :func:`compress_mixing` / :func:`make_byte_model` — glue: attach a
-  compressor to existing :class:`MixingOps` (dense or collective), and build
+  compressor to existing :class:`MixingOps` (edge-list or collective), and build
   the closed-form :class:`RoundByteModel` the trainer charges per round.
 
 The compressed path is *opt-in*: a ``MixingOps`` without a ``compression``
@@ -279,7 +279,7 @@ def compress_mixing(
     seed: int = 0,
     gamma: Optional[float] = None,
 ) -> MixingOps:
-    """Attach a compressor to any mixing operator (dense or collective).
+    """Attach a compressor to any mixing operator (edge-list or collective).
 
     ``gossip`` becomes the stateless mean-preserving compressed form;
     PISCO's round function additionally picks up the stateful error-feedback

@@ -103,7 +103,7 @@ def make_block_fn(bound: BoundAlgorithm, *, jit: bool = True) -> Callable:
     """One jitted block function scanning a block of rounds on-device:
     ``(state, flags, local, comm)`` for a static network, or
     ``(state, flags, w_gossip, w_server, local, comm)`` when ``bound.network``
-    is set — the per-round mixing matrices ride the scan exactly like the
+    is set — the per-round mixing operands ride the scan exactly like the
     pre-drawn Bernoulli(p) flags.
 
     ``flags`` is the pre-drawn bool vector (block,), ``local``/``comm`` carry
@@ -130,7 +130,7 @@ def make_block_fn(bound: BoundAlgorithm, *, jit: bool = True) -> Callable:
     else:
         def body(state, per_round):
             flag, w_gossip, w_server, local, comm = per_round
-            # Stage this round's matrices; the mixing closures inside the
+            # Stage this round's operands; the mixing closures inside the
             # round functions read them as live scan-operand tracers.
             net.slot.set(w_gossip, w_server)
             if same:
@@ -149,7 +149,7 @@ def dynamic_round_fns(
     bound: BoundAlgorithm, *, jit: bool = True
 ) -> Tuple[Callable, Callable]:
     """Per-round ``(gossip_fn, global_fn)`` for a dynamic network, each with
-    signature ``(state, local, comm, w_gossip, w_server)``: the matrices are
+    signature ``(state, local, comm, w_gossip, w_server)``: the operands are
     explicit jit arguments (fresh per round, one trace), staged into the
     network slot before the wrapped round function is traced."""
     net = bound.network
@@ -316,8 +316,7 @@ def drive_scan(
         else:
             w_gossip, w_server, messages, participants = net.draw_block(start, stop)
             realized = (messages, participants)
-            # tree-mapped: sparse networks draw pytree operands, dense draw
-            # bare matrices — both convert leafwise
+            # tree-mapped: the gossip operand is an edge-weight pytree
             state, metrics = block_fn(
                 state, jnp.asarray(flags), jax.tree.map(jnp.asarray, w_gossip),
                 jax.tree.map(jnp.asarray, w_server), local, comm,
@@ -345,7 +344,7 @@ def drive_loop(
 ):
     """The legacy per-round host loop (reference semantics).  ``round_fns``
     supplies prejitted ``(gossip_fn, global_fn)`` to reuse across drives —
-    when ``bound.network`` is set they must be the matrix-threaded form from
+    when ``bound.network`` is set they must be the operand-threaded form from
     :func:`dynamic_round_fns`."""
     net = bound.network
     if round_fns is not None:

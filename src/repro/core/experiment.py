@@ -55,19 +55,9 @@ from repro.core.driver import (
     sample_block,
     stack_rounds,
 )
-from repro.core.mixing import (
-    MixingOps,
-    make_network_mixing,
-    make_robust_agg,
-    make_sparse_network_mixing,
-)
+from repro.core.mixing import MixingOps, make_robust_agg, make_sparse_network_mixing
 from repro.core.pisco import LossFn, PiscoConfig, replicate_params
-from repro.core.topology import (
-    make_sparse_topology,
-    make_topology,
-    parse_process_spec,
-    use_sparse_topology,
-)
+from repro.core.topology import make_sparse_topology, parse_process_spec
 from repro.core.trainer import History, record_wall_time
 from repro.optim.update_rules import (
     OPT_POLICIES,
@@ -91,19 +81,13 @@ class ExperimentSpec:
     config: PiscoConfig
     topology: str = "ring"
     topology_kwargs: Tuple[Tuple[str, Any], ...] = ()
-    # Dynamic network: None => the frozen base matrix every round (legacy
+    # Dynamic network: None => the frozen base weights every round (legacy
     # path, bit-identical to pre-dynamic runs); else a TopologyProcess spec —
     # "static" | "bernoulli[:failure_prob]" | "matching" | "roundrobin[:n]".
     network: Optional[str] = None
     # Fraction of agents sampled into each server round (uniform m-of-n,
     # doubly stochastic sampled-to-sampled averaging); 1.0 => everyone.
     participation: float = 1.0
-    # Sparse substrate (DESIGN.md §12): True => edge-list/CSR mixing
-    # (shift or segment_sum gossip, O(n + m) state), False => dense n×n, None (the
-    # default, and what every legacy payload deserializes to) => auto — dense
-    # for small fleets (the bit-exact reference), sparse above
-    # SPARSE_AUTO_MIN_AGENTS.
-    sparse: Optional[bool] = None
     # Neighbor-sampled cohorts: fraction of agents seeding each gossip round
     # (only the subgraph incident to the cohort is active; sugar for
     # network="cohort:<frac>", mutually exclusive with an explicit network).
@@ -235,7 +219,10 @@ class ExperimentSpec:
     @classmethod
     def create(cls, algo: str = "pisco", **kw) -> "ExperimentSpec":
         """Flat constructor: PiscoConfig fields may be passed directly
-        (``ExperimentSpec.create(algo="pisco", n_agents=10, p=0.1, ...)``)."""
+        (``ExperimentSpec.create(algo="pisco", n_agents=10, p=0.1, ...)``).
+        The retired key ``sparse`` is accepted and dropped: every spec
+        gossips from the edge list."""
+        kw.pop("sparse", None)
         cfg_kw = {k: kw.pop(k) for k in list(kw) if k in _CONFIG_FIELDS}
         return cls(algo=algo, config=PiscoConfig(**cfg_kw), **kw)
 
@@ -260,6 +247,7 @@ class ExperimentSpec:
     @classmethod
     def from_dict(cls, d: dict) -> "ExperimentSpec":
         d = dict(d)
+        d.pop("sparse", None)  # retired key of older payloads
         d["config"] = PiscoConfig(**d["config"])
         d["topology_kwargs"] = tuple(sorted(dict(d.get("topology_kwargs", {})).items()))
         return cls(**d)
@@ -280,28 +268,14 @@ class ExperimentSpec:
             return f"cohort:{self.cohort:g}"
         return self.network
 
-    @property
-    def use_sparse(self) -> bool:
-        """Whether this spec routes through the sparse edge-list mixers."""
-        return use_sparse_topology(self.sparse, self.config.n_agents)
-
     def make_mixing(self) -> MixingOps:
-        if self.use_sparse:
-            stopo = make_sparse_topology(
-                self.topology, self.config.n_agents, **dict(self.topology_kwargs)
-            )
-            mixing = make_sparse_network_mixing(
-                stopo, self.effective_network, self.participation,
-                seed=self.config.seed,
-            )
-        else:
-            topo = make_topology(
-                self.topology, self.config.n_agents, **dict(self.topology_kwargs)
-            )
-            mixing = make_network_mixing(
-                topo, self.effective_network, self.participation,
-                seed=self.config.seed,
-            )
+        stopo = make_sparse_topology(
+            self.topology, self.config.n_agents, **dict(self.topology_kwargs)
+        )
+        mixing = make_sparse_network_mixing(
+            stopo, self.effective_network, self.participation,
+            seed=self.config.seed,
+        )
         # fault injection + robust server rule wrap BEFORE compression, so
         # corruption rides the compressed wire stream (Byzantine agents
         # corrupt what they transmit); the clean spec returns mixing as-is
@@ -324,8 +298,8 @@ class Experiment:
 
     ``sampler_factory(spec)`` builds a fresh per-round sampler for a spec (the
     hook multi-seed sweeps use); a plain ``sampler`` works for single runs.
-    ``mixing`` overrides the spec-derived dense mixer — the hook the launcher
-    uses to swap in collective (shard_map) mixers.
+    ``mixing`` overrides the spec-derived mixer — the hook the launcher uses
+    to swap in collective (shard_map) mixers.
     """
 
     def __init__(
@@ -579,7 +553,7 @@ class Experiment:
                     state, metrics = block_fn(state, jnp.asarray(flags), local, comm)
                 else:
                     # all seeds advance through the same realized network (like
-                    # the shared schedule); the matrices broadcast across the
+                    # the shared schedule); the operands broadcast across the
                     # vmapped seed axis as scan-body closure constants
                     wg, ws, messages, participants = net.draw_block(start, stop)
                     realized = (messages, participants)

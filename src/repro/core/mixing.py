@@ -2,12 +2,12 @@
 
 Two families, one interface (:class:`MixingOps`):
 
-* **Dense / simulation mixers** — agent-stacked pytrees live on one device (or
-  are auto-sharded by pjit); gossip is an einsum with the dense mixing matrix
-  ``W`` and global averaging is a mean over the agent axis.  Under ``jit`` with
-  the agent axis sharded, XLA lowers these to ``all-gather`` + local matmul and
-  ``all-reduce`` respectively — correct for *any* topology (ER, path,
-  disconnected), at the cost of an all-gather.
+* **Network mixers** — agent-stacked pytrees live on one device; gossip runs
+  from the edge list of a :class:`SparseTopology` (directed edge weights plus
+  self weights, planned by :func:`sparse_gossip`) for every fleet size and
+  every named graph, and global averaging is a mean over the agent axis.
+  The edge list is the one gossip operand: static (:func:`sparse_mixing`) or
+  drawn per round by a topology process (:func:`dynamic_sparse_mixing`).
 
 * **Collective mixers** — TPU-native path used by the launcher: gossip over a
   circulant topology (ring on the agent axis, torus over (pod, data)) becomes a
@@ -18,8 +18,7 @@ Two families, one interface (:class:`MixingOps`):
   (which the roofline analysis parses).
 
 The probabilistic `W^k = J w.p. p else W` draw is hoisted to the host launcher
-(see DESIGN.md §2): the trainer compiles one step function per mixing kind and
-dispatches per round.
+(see DESIGN.md §2): the drivers pre-draw the schedule and dispatch per round.
 """
 from __future__ import annotations
 
@@ -44,7 +43,6 @@ from repro.utils.pytree import (
     tree_agent_masked_mean,
     tree_agent_mean,
     tree_agent_median,
-    tree_agent_mix,
     tree_agent_mix_shifts,
     tree_agent_mix_sparse,
     tree_agent_trimmed_mean,
@@ -118,7 +116,7 @@ class MixingOps:
 
     gossip: Callable[[PyTree], PyTree]  # X -> X W
     global_avg: Callable[[PyTree], PyTree]  # X -> X J
-    name: str = "dense"
+    name: str = "custom"
     # Bytes moved per invocation per agent, filled in by the launcher for
     # communication-cost accounting (benchmarks fig4).
     gossip_edges: int = 0  # number of neighbor messages per gossip round
@@ -133,32 +131,12 @@ class MixingOps:
     # the byte model prices gossip at the compressor's wire format.
     compression: Optional[Any] = None
     # Optional NetworkContext for time-varying topologies / partial
-    # participation: the drivers pre-draw per-round matrices host-side and
-    # thread them through the round functions (see dynamic_dense_mixing).
+    # participation: the drivers pre-draw per-round edge weights host-side
+    # and thread them through the round functions (see dynamic_sparse_mixing).
     network: Optional["NetworkContext"] = None
-    # Sparse gossip's operand form, static per run (see sparse_gossip):
-    # "offsets/K" (K weighted shifts) or "edges"; None for other mixers.
+    # Gossip's kernel, static per run (see sparse_gossip): "offsets/K" (K
+    # weighted shifts) or "edges"; None for identity and collective mixers.
     form: Optional[str] = None
-
-
-# ---------------------------------------------------------------------------
-# Dense / simulation mixers
-# ---------------------------------------------------------------------------
-
-
-def dense_mixing(topology: Topology) -> MixingOps:
-    """Reference mixers over agent-stacked pytrees (leading axis = agents)."""
-    w = jnp.asarray(topology.w, dtype=jnp.float32)
-
-    def gossip(tree: PyTree) -> PyTree:
-        return tree_agent_mix(tree, w)
-
-    return MixingOps(
-        gossip=gossip,
-        global_avg=tree_agent_mean,
-        name=f"dense/{topology.name}",
-        gossip_edges=int(topology.adj.sum()) // 2,
-    )
 
 
 def identity_mixing(n_agents: int) -> MixingOps:
@@ -169,22 +147,22 @@ def identity_mixing(n_agents: int) -> MixingOps:
 
 
 # ---------------------------------------------------------------------------
-# Dynamic mixers: the mixing matrix is a per-round operand
+# Dynamic networks: the edge weights are a per-round operand
 # ---------------------------------------------------------------------------
 
 
 class DynamicWSlot:
-    """Trace-time injection point for the per-round mixing matrices.
+    """Trace-time injection point for the per-round mixing operands.
 
     The algorithm builders close their round functions over
     ``MixingOps.gossip`` / ``global_avg``; for a dynamic network those
-    closures read the *current* W_k from this slot.  The driver stores the
-    round's matrix operand here immediately before invoking the round
-    function **inside the same trace** (the scan body, or a wrapped loop
-    round function taking W as an explicit argument), so the read picks up
-    the live tracer and the compiled program threads the matrix as a real
-    input — nothing is baked in as a constant, and no algorithm needs a
-    signature change.
+    closures read the *current* round's edge weights (and server operand)
+    from this slot.  The driver stores the round's operands here immediately
+    before invoking the round function **inside the same trace** (the scan
+    body, or a wrapped loop round function taking them as explicit
+    arguments), so the read picks up the live tracers and the compiled
+    program threads the operands as real inputs — nothing is baked in as a
+    constant, and no algorithm needs a signature change.
     """
 
     __slots__ = ("gossip_w", "server_w")
@@ -211,9 +189,6 @@ class NetworkContext:
     process: TopologyProcess
     slot: DynamicWSlot
     participation: Optional[ParticipationProcess] = None
-    # Sparse operand mode: draw per-round *edge weights* (pytree operands)
-    # instead of dense matrices — the drivers thread either shape untouched.
-    sparse: bool = False
 
     @property
     def n_agents(self) -> int:
@@ -224,35 +199,25 @@ class NetworkContext:
         ``[start, stop)``; operands carry a leading round axis (scan
         operands), counts are host ints for the byte accountant.
 
-        Dense mode: ``w_gossip`` is (block, n, n); without participation the
-        server matrix is a (block, 1, 1) placeholder — ``global_avg`` is the
-        exact mean and never reads it.  Sparse mode: ``w_gossip`` is the
-        pytree ``{'edge_w': (block, 2m), 'self_w': (block, n)}`` over the
-        directed base-edge order and ``w_server`` a (block, n) participant
-        mask (or a (block, 1) placeholder).  Message/participant counts are
-        identical in both modes — byte pricing can't tell them apart."""
+        ``w_gossip`` is the pytree ``{'edge_w': (block, 2m), 'self_w':
+        (block, n)}`` over the directed base-edge order; ``w_server`` is a
+        (block, n) participant mask, or without participation a (block, 1)
+        placeholder — ``global_avg`` is then the exact mean and never reads
+        it."""
         block = stop - start
-        if self.sparse:
-            edge_w, self_w, messages = self.process.draw_sparse_block(start, stop)
-            # duplicate per-undirected-edge weights across both orientations
-            w_gossip = {
-                "edge_w": np.concatenate([edge_w, edge_w], axis=1),
-                "self_w": self_w,
-            }
-            if self.participation is None:
-                w_server = np.zeros((block, 1), dtype=np.float32)
-                participants = np.full(block, self.n_agents, dtype=int)
-            else:
-                w_server, participants = self.participation.draw_mask_block(
-                    start, stop
-                )
-            return w_gossip, w_server, messages, participants
-        w_gossip, messages = self.process.draw_block(start, stop)
+        edge_w, self_w, messages = self.process.draw_sparse_block(start, stop)
+        # duplicate per-undirected-edge weights across both orientations
+        w_gossip = {
+            "edge_w": np.concatenate([edge_w, edge_w], axis=1),
+            "self_w": self_w,
+        }
         if self.participation is None:
-            w_server = np.zeros((block, 1, 1), dtype=np.float32)
+            w_server = np.zeros((block, 1), dtype=np.float32)
             participants = np.full(block, self.n_agents, dtype=int)
         else:
-            w_server, participants = self.participation.draw_block(start, stop)
+            w_server, participants = self.participation.draw_mask_block(
+                start, stop
+            )
         return w_gossip, w_server, messages, participants
 
     def draw_round(self, k: int):
@@ -262,73 +227,8 @@ class NetworkContext:
         return first(wg), first(ws), int(msgs[0]), int(parts[0])
 
 
-def dynamic_dense_mixing(
-    process: TopologyProcess,
-    *,
-    participation: float = 1.0,
-    participation_seed: Optional[int] = None,
-) -> MixingOps:
-    """Dense mixers over a time-varying network.
-
-    ``gossip`` applies whatever W_k the driver staged in the slot for the
-    current round; ``global_avg`` is the exact mean when every agent
-    participates, else the doubly stochastic sampled-to-sampled matrix S_k
-    (participants average among themselves, absentees hold — the network
-    mean is preserved, so gradient tracking's Lemma-1 invariant survives).
-    """
-    slot = DynamicWSlot()
-    part = None
-    if participation < 1.0:
-        part = ParticipationProcess(
-            process.n_agents,
-            participation,
-            seed=process.seed if participation_seed is None else participation_seed,
-        )
-
-    def gossip(tree: PyTree) -> PyTree:
-        return tree_agent_mix(tree, slot.gossip_w)
-
-    if part is None:
-        global_avg = tree_agent_mean
-    else:
-        def global_avg(tree: PyTree) -> PyTree:
-            return tree_agent_mix(tree, slot.server_w)
-
-    base = process.base
-    name = f"dynamic/{process.spec()}/{base.name}"
-    if part is not None:
-        name += f"/m{part.m}of{part.n_agents}"
-    return MixingOps(
-        gossip=gossip,
-        global_avg=global_avg,
-        name=name,
-        gossip_edges=int(base.adj.sum()) // 2,
-        network=NetworkContext(process=process, slot=slot, participation=part),
-    )
-
-
-def make_network_mixing(
-    topology: Topology,
-    network: Optional[str] = None,
-    participation: float = 1.0,
-    *,
-    seed: int = 0,
-) -> MixingOps:
-    """Dense mixers for an optionally dynamic network — the one selection
-    point shared by ``ExperimentSpec.make_mixing`` and the launch CLI.
-
-    ``network=None`` with full participation is the legacy frozen-matrix
-    path (bit-identical to pre-dynamic runs); anything else routes through
-    :func:`dynamic_dense_mixing` over the parsed :class:`TopologyProcess`.
-    """
-    if network is None and participation >= 1.0:
-        return dense_mixing(topology)
-    process = make_topology_process(network, topology, seed=seed)
-    return dynamic_dense_mixing(process, participation=participation)
-
-
 # ---------------------------------------------------------------------------
-# Sparse mixers: gossip from the edge list, never materializing n×n
+# Gossip from the edge list, never materializing n×n
 # ---------------------------------------------------------------------------
 
 
@@ -386,7 +286,7 @@ def sparse_gossip(topo: SparseTopology):
 def sparse_mixing(topology: SparseTopology) -> MixingOps:
     """Static sparse mixers over the fixed edge list with precomputed
     Metropolis weights — O(n + m) state instead of O(n^2), numerically equal
-    to ``dense_mixing`` over the materialized W up to float reassociation.
+    to ``W @ x`` over the materialized W up to float reassociation.
     Gossip runs in the form :func:`sparse_gossip` picks from the edge list:
     weighted shifts on a banded graph, gather + ``segment_sum`` otherwise."""
     form, mix = sparse_gossip(topology)
@@ -417,11 +317,11 @@ def dynamic_sparse_mixing(
 
     The per-round operand is the edge-weight pytree the driver stages in the
     slot (``{'edge_w': (2m,), 'self_w': (n,)}`` in base directed-edge order,
-    dropped edges zeroed) — fixed shapes, so ``lax.scan`` threads it like
-    the dense W_k, at O(n + m) instead of O(n^2) per round.  Partial
-    participation uses the O(n) masked-mean form of the sampled-to-sampled
-    matrix (mean-preserving, so gradient tracking's Lemma-1 invariant
-    survives, same as the dense path).
+    dropped edges zeroed) — fixed shapes, so ``lax.scan`` threads it at
+    O(n + m) per round.  Partial participation uses the O(n) masked-mean
+    form of the doubly stochastic sampled-to-sampled matrix S_k
+    (participants average among themselves, absentees hold — the network
+    mean is preserved, so gradient tracking's Lemma-1 invariant survives).
     """
     slot = DynamicWSlot()
     part = None
@@ -452,9 +352,7 @@ def dynamic_sparse_mixing(
         global_avg=global_avg,
         name=name,
         gossip_edges=base.n_edges,
-        network=NetworkContext(
-            process=process, slot=slot, participation=part, sparse=True
-        ),
+        network=NetworkContext(process=process, slot=slot, participation=part),
         form=form,
     )
 
@@ -466,8 +364,13 @@ def make_sparse_network_mixing(
     *,
     seed: int = 0,
 ) -> MixingOps:
-    """Sparse counterpart of :func:`make_network_mixing` — same selection
-    logic, edge-list operands throughout."""
+    """Mixers for an optionally dynamic network — the one selection point
+    shared by ``ExperimentSpec.make_mixing`` and the launch CLI.
+
+    ``network=None`` with full participation is the frozen-weight path
+    (:func:`sparse_mixing`); anything else routes through
+    :func:`dynamic_sparse_mixing` over the parsed :class:`TopologyProcess`.
+    """
     if network is None and participation >= 1.0:
         return sparse_mixing(topology)
     process = make_topology_process(network, topology, seed=seed)

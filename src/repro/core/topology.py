@@ -1,8 +1,10 @@
 """Communication graphs and mixing matrices (paper §2.1).
 
-Everything here is *host-side* (numpy): topologies are static metadata that the
-launcher turns into either a dense mixing matrix (general ``W``) or a neighbor
-schedule for ``ppermute``-based collective mixing.
+Everything here is *host-side* (numpy): topologies are static metadata.
+Gossip runs from the edge list (:class:`SparseTopology`, the operand
+:func:`repro.core.mixing.sparse_gossip` plans); :class:`Topology` keeps the
+dense ``W`` for spectral analysis and for the mesh path's collective mixers
+(a neighbor schedule for ``ppermute``).
 
 Definition 1 of the paper: ``W`` is nonnegative, doubly stochastic, with
 ``w_ij = 0`` iff ``{i,j}`` is not an edge (i != j), and the mixing rate is
@@ -242,13 +244,14 @@ def is_connected(adj: np.ndarray) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Topology: the launcher-facing bundle
+# Topology: the dense analysis bundle
 # ---------------------------------------------------------------------------
 
 
 @dataclasses.dataclass(frozen=True)
 class Topology:
-    """A gossip graph + weighting, with everything the mixers need."""
+    """A gossip graph + weighting as a dense ``W``: spectral analysis
+    (``lambda_w``) and the input of the mesh path's collective mixers."""
 
     name: str
     n_agents: int
@@ -257,7 +260,7 @@ class Topology:
     lambda_w: float
     connected: bool
     # For collective (ppermute) mixing: neighbor shifts valid for
-    # shift-invariant graphs (ring/torus); None => dense mixing only.
+    # shift-invariant graphs (ring/torus); None => collective_dense_mixing.
     shifts: Optional[tuple] = None  # tuple of (shift, weight) incl. (0, w_self)
 
     def expected_rate(self, p: float) -> float:
@@ -325,18 +328,8 @@ def make_topology(
 # Sparse topologies: edge-list / CSR representation, never materializing n×n
 # ---------------------------------------------------------------------------
 
-# Below this many agents the dense path is auto-selected (ExperimentSpec
-# ``sparse=None``): dense einsum gossip is faster for small fleets and stays
-# the bit-exact reference the parity tests pin against.
-SPARSE_AUTO_MIN_AGENTS = 512
-
-
-def use_sparse_topology(flag: Optional[bool], n_agents: int) -> bool:
-    """Resolve the three-state ``sparse`` spec field: explicit True/False
-    wins; ``None`` auto-selects sparse only for large fleets."""
-    if flag is not None:
-        return bool(flag)
-    return n_agents > SPARSE_AUTO_MIN_AGENTS
+# Up to this many agents ``lambda_w`` is computed (a dense spectral norm).
+_LAMBDA_MAX_AGENTS = 512
 
 
 def _canonical_edges(edges) -> np.ndarray:
@@ -438,8 +431,8 @@ def _csr_neighbors(n: int, edges: np.ndarray):
 
 @dataclasses.dataclass(frozen=True)
 class SparseTopology:
-    """A gossip graph in edge-list / CSR form — the large-fleet counterpart
-    of :class:`Topology`, built without ever materializing an n×n array.
+    """A gossip graph in edge-list / CSR form — the operand every gossip
+    mixer runs from, built without ever materializing an n×n array.
 
     ``edges`` is the canonical (i < j, lexicographic) undirected edge list;
     ``edge_weight``/``self_weight`` are its Metropolis–Hastings weights
@@ -494,7 +487,7 @@ def sparse_topology_from_edges(
     data = np.concatenate([edge_w, edge_w])[order]
     indptr = np.searchsorted(receivers[order], np.arange(n_agents + 1)).astype(int)
     lam = None
-    if n_agents <= SPARSE_AUTO_MIN_AGENTS:
+    if n_agents <= _LAMBDA_MAX_AGENTS:
         w = np.zeros((n_agents, n_agents), dtype=np.float64)
         if len(edges):
             w[edges[:, 0], edges[:, 1]] = edge_w
@@ -623,7 +616,8 @@ class TopologyProcess:
     Realizations are drawn **host-side** and are pure functions of
     ``(seed, k)`` — the same contract as the Bernoulli(p) schedule in
     :mod:`repro.core.driver` — so the scan driver can pre-draw a whole block
-    (:meth:`draw_block`) and still agree round-for-round with the legacy loop.
+    (:meth:`draw_sparse_block`) and still agree round-for-round with the
+    legacy loop.
     """
 
     kind = "abstract"
@@ -670,7 +664,7 @@ class TopologyProcess:
             mask[self._edge_index[(min(int(i), int(j)), max(int(i), int(j)))]] = True
         return mask
 
-    # -- derived ------------------------------------------------------------
+    # -- dense references (host-side analysis and tests) --------------------
 
     def realize(self, k: int):
         """``(W_k, directed_messages)`` from one edge realization."""
@@ -689,16 +683,7 @@ class TopologyProcess:
         """Directed neighbor messages one gossip mix moves in round ``k``."""
         return self.realize(k)[1]
 
-    def draw_block(self, start: int, stop: int):
-        """Stacked ``(W, messages)`` for rounds ``[start, stop)``: W is
-        (block, n, n) float32 (a ``lax.scan`` operand), messages (block,) int
-        (what the byte accountant prices)."""
-        realized = [self.realize(k) for k in range(start, stop)]
-        ws = np.stack([w for w, _ in realized]).astype(np.float32)
-        msgs = np.array([m for _, m in realized])
-        return ws, msgs
-
-    # -- sparse realizations (edge sets instead of matrices) ----------------
+    # -- realizations: the operands the drivers thread ----------------------
 
     def realize_sparse(self, k: int):
         """``(edge_w, self_w, directed_messages)`` for round ``k`` in *base*
@@ -722,8 +707,7 @@ class TopologyProcess:
     def draw_sparse_block(self, start: int, stop: int):
         """Stacked ``(edge_w, self_w, messages)`` for rounds ``[start, stop)``:
         edge_w (block, m) and self_w (block, n) float32 scan operands over the
-        base edge order, messages (block,) host ints for the byte accountant
-        — the sparse analogue of :meth:`draw_block`."""
+        base edge order, messages (block,) host ints for the byte accountant."""
         realized = [self.realize_sparse(k) for k in range(start, stop)]
         edge_w = np.stack([r[0] for r in realized]).astype(np.float32)
         self_w = np.stack([r[1] for r in realized]).astype(np.float32)
@@ -978,29 +962,21 @@ class ParticipationProcess:
         return np.sort(rng.choice(self.n_agents, size=self.m, replace=False))
 
     def server_matrix_at(self, k: int) -> np.ndarray:
+        """The dense S_k (host-side reference for the mask form)."""
         part = self.participants_at(k)
         s = np.eye(self.n_agents, dtype=np.float64)
         s[np.ix_(part, part)] = 1.0 / len(part)
         return s
 
-    def draw_block(self, start: int, stop: int):
-        """Stacked ``(S, participants)`` for rounds ``[start, stop)``."""
-        ss = np.stack(
-            [self.server_matrix_at(k) for k in range(start, stop)]
-        ).astype(np.float32)
-        counts = np.full(stop - start, self.m, dtype=int)
-        return ss, counts
-
     def participant_mask_at(self, k: int) -> np.ndarray:
         """Round-``k`` participation as a (n,) float32 0/1 mask — the O(n)
-        operand form the sparse mixers consume instead of the n×n S_k."""
+        operand the mixers consume instead of the n×n S_k."""
         mask = np.zeros(self.n_agents, dtype=np.float32)
         mask[self.participants_at(k)] = 1.0
         return mask
 
     def draw_mask_block(self, start: int, stop: int):
-        """Stacked ``(mask, participants)`` for rounds ``[start, stop)`` —
-        the sparse analogue of :meth:`draw_block`."""
+        """Stacked ``(mask, participants)`` for rounds ``[start, stop)``."""
         masks = np.stack(
             [self.participant_mask_at(k) for k in range(start, stop)]
         )

@@ -47,7 +47,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from repro.core.topology import metropolis_edge_weights, metropolis_weights
+from repro.core.topology import metropolis_edge_weights
 from repro.events.staleness import AsyncConfig, parse_async_spec, staleness_weights
 from repro.sim.costmodel import SystemsModel, make_time_model
 
@@ -150,7 +150,6 @@ class EventEngine:
     server_bytes: int = 0
     mixes: int = 1
     payloads: int = 1
-    sparse: bool = False
 
     def __post_init__(self):
         self.flags = np.asarray(self.flags, dtype=bool)
@@ -289,41 +288,24 @@ class EventEngine:
 
     def draw_block(self, start: int, stop: int):
         """Event-derived mixing operands for rounds ``[start, stop)`` in the
-        shapes :func:`~repro.core.driver.make_block_fn` threads: dense W_k
-        stacks (or sparse edge-weight pytrees) for gossip, and the
-        ``{'w', 'keep'}`` staleness-weight pytree for the buffered server
-        average — same contract as ``NetworkContext.draw_block``."""
+        shapes :func:`~repro.core.driver.make_block_fn` threads: edge-weight
+        pytrees over the base edge order for gossip, and the ``{'w',
+        'keep'}`` staleness-weight pytree for the buffered server average —
+        same contract as ``NetworkContext.draw_block``."""
         n, edges = self.n_agents, self.base_edges
         active = self.trace["active"]
-        if self.sparse:
-            m = len(edges)
-            ew = np.zeros((stop - start, m), dtype=np.float32)
-            sw = np.ones((stop - start, n), dtype=np.float32)
-            for t, k in enumerate(range(start, stop)):
-                if self.flags[k]:
-                    continue  # unused branch operand at server rounds
-                mask = active[k]
-                if mask.any():
-                    sub_w, self_w = metropolis_edge_weights(edges[mask], n)
-                    ew[t, mask] = sub_w
-                    sw[t] = self_w
-            w_gossip = {
-                "edge_w": np.concatenate([ew, ew], axis=1), "self_w": sw
-            }
-        else:
-            ws = np.empty((stop - start, n, n), dtype=np.float32)
-            eye = np.eye(n, dtype=np.float32)
-            for t, k in enumerate(range(start, stop)):
-                if self.flags[k]:
-                    ws[t] = eye  # unused branch operand at server rounds
-                else:
-                    adj = np.zeros((n, n), dtype=bool)
-                    e = edges[active[k]]
-                    if len(e):
-                        adj[e[:, 0], e[:, 1]] = True
-                        adj[e[:, 1], e[:, 0]] = True
-                    ws[t] = metropolis_weights(adj)
-            w_gossip = ws
+        m = len(edges)
+        ew = np.zeros((stop - start, m), dtype=np.float32)
+        sw = np.ones((stop - start, n), dtype=np.float32)
+        for t, k in enumerate(range(start, stop)):
+            if self.flags[k]:
+                continue  # unused branch operand at server rounds
+            mask = active[k]
+            if mask.any():
+                sub_w, self_w = metropolis_edge_weights(edges[mask], n)
+                ew[t, mask] = sub_w
+                sw[t] = self_w
+        w_gossip = {"edge_w": np.concatenate([ew, ew], axis=1), "self_w": sw}
         return (
             w_gossip,
             self._server_ops(start, stop),
@@ -362,5 +344,4 @@ def make_event_engine(
         server_bytes=tm.server_message_bytes,
         mixes=tm.mixes_per_round,
         payloads=tm.server_payloads,
-        sparse=bool(getattr(spec, "use_sparse", False)),
     )

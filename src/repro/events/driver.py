@@ -4,12 +4,12 @@
 :mod:`repro.core.driver` (``record_block`` / ``maybe_eval`` /
 ``make_block_fn``): the numerics still run as chunked on-device scans over
 the registry's round functions **unchanged** — what changes is where the
-per-round operands come from.  A synchronous driver draws mixing matrices
+per-round operands come from.  A synchronous driver draws edge weights
 from the topology process and prices rounds with the barrier time model; the
 events driver draws both from the :class:`~repro.events.clock.EventEngine`:
 
-* gossip matrices are built from the *active* edge set — realized edges minus
-  those incident to agents beyond the staleness bound;
+* gossip edge weights are built from the *active* edge set — realized edges
+  minus those incident to agents beyond the staleness bound;
 * server rounds average with the buffered aggregator's staleness weights via
   :func:`~repro.utils.pytree.tree_agent_weighted_mean`, staged through the
   same :class:`~repro.core.mixing.DynamicWSlot` mechanism as any dynamic
@@ -40,9 +40,9 @@ from repro.core.driver import (
     sample_block,
 )
 from repro.core.mixing import DynamicWSlot, MixingOps, sparse_gossip
-from repro.core.topology import make_sparse_topology, make_topology
+from repro.core.topology import make_sparse_topology
 from repro.events.clock import EventEngine
-from repro.utils.pytree import tree_agent_mix, tree_agent_weighted_mean
+from repro.utils.pytree import tree_agent_weighted_mean
 
 PyTree = Any
 
@@ -57,47 +57,30 @@ class EventNetwork:
     """
 
     events = True
-    __slots__ = ("slot", "sparse")
+    __slots__ = ("slot",)
 
-    def __init__(self, slot: DynamicWSlot, sparse: bool):
+    def __init__(self, slot: DynamicWSlot):
         self.slot = slot
-        self.sparse = sparse
 
 
 def make_async_mixing(spec: Any) -> MixingOps:
     """Mixing ops whose per-round operands are event-engine decisions.
 
-    Gossip reads whatever W_k (dense) or edge-weight pytree (sparse) the
-    driver staged for the current round — exactly the dynamic-network slot
-    mechanism — built by the engine from the staleness-masked active edge
-    set.  The global average reads the engine's ``{'w', 'keep'}`` staleness
-    weights: participants are averaged with the buffered aggregator's
-    normalized weights, absentees hold.  Compression wraps on top like any
+    Gossip reads the edge-weight pytree the driver staged for the current
+    round — exactly the dynamic-network slot mechanism — built by the engine
+    from the staleness-masked active edge set.  The global average reads the
+    engine's ``{'w', 'keep'}`` staleness weights: participants are averaged
+    with the buffered aggregator's normalized weights, absentees hold.  Compression wraps on top like any
     other mixing, so the error-feedback wire path is identical.
     """
     slot = DynamicWSlot()
     n = spec.config.n_agents
-    if spec.use_sparse:
-        stopo = make_sparse_topology(
-            spec.topology, n, **dict(spec.topology_kwargs)
-        )
-        form, mix = sparse_gossip(stopo)
+    stopo = make_sparse_topology(spec.topology, n, **dict(spec.topology_kwargs))
+    form, mix = sparse_gossip(stopo)
 
-        def gossip(tree: PyTree) -> PyTree:
-            ops = slot.gossip_w
-            return mix(tree, ops["edge_w"], ops["self_w"])
-
-        gossip_edges = stopo.n_edges
-        base_name = stopo.name
-    else:
-        topo = make_topology(spec.topology, n, **dict(spec.topology_kwargs))
-
-        def gossip(tree: PyTree) -> PyTree:
-            return tree_agent_mix(tree, slot.gossip_w)
-
-        gossip_edges = int(topo.adj.sum()) // 2
-        base_name = topo.name
-        form = None
+    def gossip(tree: PyTree) -> PyTree:
+        ops = slot.gossip_w
+        return mix(tree, ops["edge_w"], ops["self_w"])
 
     def global_avg(tree: PyTree) -> PyTree:
         ops = slot.server_w
@@ -106,9 +89,9 @@ def make_async_mixing(spec: Any) -> MixingOps:
     mixing = MixingOps(
         gossip=gossip,
         global_avg=global_avg,
-        name=f"events/{base_name}",
-        gossip_edges=gossip_edges,
-        network=EventNetwork(slot, spec.use_sparse),
+        name=f"events/{stopo.name}",
+        gossip_edges=stopo.n_edges,
+        network=EventNetwork(slot),
         form=form,
     )
     if getattr(spec, "adversary", None) is not None:

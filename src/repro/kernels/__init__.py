@@ -1,8 +1,7 @@
 """Pallas TPU kernels for the compute hot spots (validated interpret=True on
 CPU): flash_attention (prefill/train attention), ssd_scan (Mamba-2 chunked
 scan), gt_update (fused PISCO local-step / mix-combine elementwise passes),
-quantize (fused quantize→mix→dequantize for compressed gossip), sparse_mix
-(edge-list gossip scatter-accumulate, plain and compressed).
+quantize (fused quantize→mix→dequantize for compressed gossip).
 
 The paper itself has no kernel-level contribution (its contribution is the
 communication protocol); these kernels target the workloads PISCO trains plus
@@ -13,16 +12,10 @@ from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gt_update import fused_local_step, fused_mix_combine
 from repro.kernels.quantize import fused_compressed_mix, rowwise_quant_dequant
-from repro.kernels.sparse_mix import (
-    sparse_compressed_mix,
-    sparse_mix,
-    topology_edge_arrays,
-)
 from repro.kernels.ssd_scan import ssd_scan_kernel
 
 __all__ = [
     "ops", "ref", "flash_attention", "fused_local_step",
     "fused_mix_combine", "fused_compressed_mix", "rowwise_quant_dequant",
-    "sparse_mix", "sparse_compressed_mix", "topology_edge_arrays",
     "ssd_scan_kernel",
 ]

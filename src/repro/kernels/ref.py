@@ -128,35 +128,3 @@ def compressed_mix_ref(x: jnp.ndarray, w: jnp.ndarray, bits: int) -> jnp.ndarray
     xf = x.astype(jnp.float32)
     return (xf + w.astype(jnp.float32) @ q - q).astype(x.dtype)
 
-
-def sparse_mix_ref(
-    x: jnp.ndarray,
-    senders: jnp.ndarray,
-    receivers: jnp.ndarray,
-    edge_w: jnp.ndarray,
-    self_w: jnp.ndarray,
-) -> jnp.ndarray:
-    """Edge-list gossip:  out_i = self_w_i·x_i + Σ_{e: s_e→i} w_e·x_{s_e}."""
-    xf = x.astype(jnp.float32)
-    contrib = edge_w.astype(jnp.float32)[:, None] * xf[senders]
-    acc = jax.ops.segment_sum(contrib, receivers, num_segments=x.shape[0])
-    return (self_w.astype(jnp.float32)[:, None] * xf + acc).astype(x.dtype)
-
-
-def sparse_compressed_mix_ref(
-    x: jnp.ndarray,
-    senders: jnp.ndarray,
-    receivers: jnp.ndarray,
-    edge_w: jnp.ndarray,
-    self_w: jnp.ndarray,
-    bits: int,
-    gamma: float = 1.0,
-) -> jnp.ndarray:
-    """Mean-preserving compressed gossip over an edge list:
-    x + γ·(W·q(x) − q(x)) with the implicit sparse W."""
-    q = rowwise_quant_dequant_ref(x, bits)
-    mixed = sparse_mix_ref(q, senders, receivers, edge_w, self_w).astype(
-        jnp.float32
-    )
-    xf = x.astype(jnp.float32)
-    return (xf + gamma * (mixed - q.astype(jnp.float32))).astype(x.dtype)

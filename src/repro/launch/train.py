@@ -40,11 +40,10 @@ from repro.core.adversary import (
     unwrap_network,
 )
 from repro.core.experiment import Experiment, ExperimentSpec
-from repro.core.mixing import make_network_mixing
+from repro.core.mixing import make_sparse_network_mixing
 from repro.core.pisco import PiscoConfig, replicate_params
 from repro.core.trainer import History
-from repro.core.mixing import make_sparse_network_mixing
-from repro.core.topology import make_sparse_topology, make_topology
+from repro.core.topology import make_sparse_topology
 from repro.optim.update_rules import RULE_NAMES, resolve_update_rules
 from repro.data.synthetic import synthetic_lm_tokens
 from repro.models import get_bundle
@@ -128,11 +127,6 @@ def main(argv=None) -> int:
                          "(default: frozen base W)")
     ap.add_argument("--participation", type=float, default=1.0,
                     help="fraction of agents sampled into each server round")
-    ap.add_argument("--sparse", action="store_true",
-                    help="edge-list/CSR mixing (shift or segment_sum gossip, "
-                         "O(n+m) state) — required for large fleets; "
-                         "default dense n x n (auto-selected by "
-                         "ExperimentSpec above 512 agents)")
     ap.add_argument("--cohort", type=float, default=None,
                     help="neighbor-sampled cohorts: fraction of agents "
                          "seeding each gossip round (sugar for "
@@ -235,16 +229,10 @@ def main(argv=None) -> int:
     network = (
         f"cohort:{args.cohort:g}" if args.cohort is not None else args.network
     )
-    if args.sparse:
-        topo = make_sparse_topology(args.topology, args.n_agents)
-        mixing = make_sparse_network_mixing(
-            topo, network, args.participation, seed=args.seed
-        )
-    else:
-        topo = make_topology(args.topology, args.n_agents)
-        mixing = make_network_mixing(
-            topo, network, args.participation, seed=args.seed
-        )
+    topo = make_sparse_topology(args.topology, args.n_agents)
+    mixing = make_sparse_network_mixing(
+        topo, network, args.participation, seed=args.seed
+    )
     # fault injection + robust server rule compose as a mixing wrapper, the
     # same way ExperimentSpec.make_mixing layers them (before compression)
     mixing = make_adversarial_mixing(
@@ -253,7 +241,7 @@ def main(argv=None) -> int:
     )
     lam = "n/a" if topo.lambda_w is None else f"{topo.lambda_w:.4f}"
     print(f"arch={cfg.name} params~{cfg.param_count():,} agents={args.n_agents} "
-          f"topology={'sparse/' if args.sparse else ''}{args.topology} "
+          f"topology={args.topology} "
           f"network={network or 'frozen'} "
           f"participation={args.participation:g} lambda_w={lam} "
           f"p={args.p}")
@@ -294,8 +282,7 @@ def main(argv=None) -> int:
     spec = ExperimentSpec.create(
         algo=args.algo, n_agents=args.n_agents, t_o=args.t_o,
         eta_l=args.eta_l, eta_c=args.eta_c, p=args.p, seed=args.seed,
-        topology=args.topology, network=args.network,
-        sparse=args.sparse or None, cohort=args.cohort,
+        topology=args.topology, network=args.network, cohort=args.cohort,
         participation=args.participation,
         systems=args.systems or ("uniform" if args.tune else None),
         async_=async_spec,

@@ -109,7 +109,6 @@ GATES: Dict[str, List[MetricGate]] = {
     "sparse": [
         MetricGate("results.n=10000.sparse_mixing_state_bytes", "match", 0.0),
         MetricGate("results.n=10000.per_round_s", "time", WALL_TOL),
-        MetricGate("parity.ok", "flag"),
     ],
     "robust": [
         MetricGate("robustness_flip", "flag"),

@@ -32,12 +32,9 @@ from repro.core.schedule import RoundByteModel
 from repro.core.topology import (
     ParticipationProcess,
     TopologyProcess,
-    edge_list,
     make_sparse_topology,
-    make_topology,
     make_topology_process,
     topology_edges,
-    use_sparse_topology,
 )
 from repro.sim.profiles import SystemsParams, make_profile
 
@@ -240,15 +237,9 @@ def make_time_model(
         part = network.participation
         base_edges = topology_edges(process.base)
     else:
-        # mirror the spec's dense/sparse selection so large sparse fleets are
-        # priced without an O(n^2) adjacency materialization
-        if use_sparse_topology(getattr(spec, "sparse", None), n):
-            topo = make_sparse_topology(
-                spec.topology, n, **dict(spec.topology_kwargs)
-            )
-        else:
-            topo = make_topology(spec.topology, n, **dict(spec.topology_kwargs))
-        base_edges = topology_edges(topo)
+        # the same edge list the spec's mixer gossips over, O(n + m)
+        topo = make_sparse_topology(spec.topology, n, **dict(spec.topology_kwargs))
+        base_edges = topo.edges
         net_spec = getattr(spec, "effective_network", spec.network)
         if net_spec is None and spec.participation >= 1.0:
             process, part = None, None  # legacy frozen-W path

@@ -9,7 +9,6 @@ from repro.utils.pytree import (
     tree_dot,
     tree_sq_norm,
     tree_agent_mean,
-    tree_agent_mix,
     tree_size,
     tree_bytes,
 )
@@ -25,7 +24,6 @@ __all__ = [
     "tree_dot",
     "tree_sq_norm",
     "tree_agent_mean",
-    "tree_agent_mix",
     "tree_size",
     "tree_bytes",
 ]

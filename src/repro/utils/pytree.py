@@ -62,27 +62,9 @@ def tree_agent_mean(tree):
     )
 
 
-def tree_agent_mix(tree, w):
-    """Apply a mixing matrix ``w`` (n, n) along the leading agent axis.
-
-    Computes, per leaf ``x`` of shape (n, ...):   out_i = sum_j w_ij x_j,
-    i.e. the compact-form ``X W^T``... note the paper writes states as columns
-    (``X in R^{d x n}``, update ``X W``); with our leading-agent-axis layout the
-    equivalent contraction is ``einsum('ij,j...->i...', W^T, x)``.  Since all
-    mixing matrices here are symmetric and doubly stochastic, ``W^T = W``;
-    we still transpose to stay correct for any future asymmetric matrix.
-    """
-    wt = jnp.asarray(w).T
-
-    def mix(x):
-        return jnp.tensordot(wt, x, axes=((1,), (0,))).astype(x.dtype)
-
-    return jax.tree.map(mix, tree)
-
-
 def tree_agent_mix_sparse(tree, senders, receivers, edge_w, self_w, n_agents):
-    """Sparse gossip over directed edges — the edge-list form of
-    :func:`tree_agent_mix` without ever materializing W.
+    """Sparse gossip over directed edges: ``W @ x`` without ever
+    materializing W.
 
     Per leaf ``x`` of shape (n, ...):
 
@@ -92,7 +74,7 @@ def tree_agent_mix_sparse(tree, senders, receivers, edge_w, self_w, n_agents):
     symmetric realization the directed arrays are the two orientations of
     each undirected edge with the weight duplicated; per-round edge dropout
     is expressed as zeros in ``edge_w`` (fixed shapes, so scan can thread
-    the weights as operands).  Accumulates in float32, like the dense path.
+    the weights as operands).  Accumulates in float32.
     ``core.mixing.sparse_gossip`` runs this form only for graphs that are
     not banded; banded ones take :func:`tree_agent_mix_shifts`.
     """

@@ -64,3 +64,83 @@ def make_logreg_problem(n_agents=8, d=16, m=64, seed=0, heterogeneous=True):
         return sampler
 
     return loss_fn, full_grad_sq, sampler_factory, d
+
+
+# ---------------------------------------------------------------------------
+# Reference mixers: dense W @ x, the yardstick for the edge-list mixers
+# ---------------------------------------------------------------------------
+
+
+def reference_mix(tree, w):
+    """``W @ x`` along the leading agent axis of every leaf, in float32 at
+    HIGHEST precision (a TPU's default precision would round to bfloat16)."""
+    import jax.numpy as jnp
+
+    w = jnp.asarray(w, jnp.float32)
+
+    def mix(x):
+        out = jnp.tensordot(w, x.astype(jnp.float32), axes=1,
+                            precision=jax.lax.Precision.HIGHEST)
+        return out.astype(x.dtype)
+
+    return jax.tree.map(mix, tree)
+
+
+def reference_mixing(topo):
+    """Static mixers over the dense W of a ``Topology`` (``.w``) or a
+    ``SparseTopology`` (``.dense_w()``), priced over the same edges."""
+    from repro.core import MixingOps, topology_edges
+    from repro.utils.pytree import tree_agent_mean
+
+    w = topo.dense_w() if hasattr(topo, "dense_w") else topo.w
+    return MixingOps(
+        gossip=lambda tree: reference_mix(tree, w),
+        global_avg=tree_agent_mean,
+        name=f"reference/{topo.name}",
+        gossip_edges=len(topology_edges(topo)),
+    )
+
+
+class ReferenceNetwork:
+    """The drivers' network contract over dense per-round W_k, realized by
+    the process's host-side ``realize`` (full participation)."""
+
+    def __init__(self, process):
+        from repro.core.mixing import DynamicWSlot
+
+        self.process = process
+        self.participation = None
+        self.slot = DynamicWSlot()
+
+    def draw_block(self, start, stop):
+        realized = [self.process.realize(k) for k in range(start, stop)]
+        ws = np.stack([w for w, _ in realized]).astype(np.float32)
+        messages = np.array([m for _, m in realized])
+        w_server = np.zeros((stop - start, 1), np.float32)  # never read
+        return ws, w_server, messages, np.full(stop - start, self.process.n_agents)
+
+    def draw_round(self, k):
+        ws, w_server, messages, parts = self.draw_block(k, k + 1)
+        return ws[0], w_server[0], int(messages[0]), int(parts[0])
+
+
+def reference_network_mixing(spec):
+    """Dense reference mixers for an ``ExperimentSpec``'s (optionally
+    dynamic) network: the frozen W, or W_k drawn per round."""
+    from repro.core import MixingOps, make_topology, make_topology_process
+    from repro.utils.pytree import tree_agent_mean
+
+    assert spec.participation == 1.0
+    topo = make_topology(spec.topology, spec.config.n_agents, **dict(spec.topology_kwargs))
+    if spec.effective_network is None:
+        return reference_mixing(topo)
+    net = ReferenceNetwork(
+        make_topology_process(spec.effective_network, topo, seed=spec.config.seed)
+    )
+    return MixingOps(
+        gossip=lambda tree: reference_mix(tree, net.slot.gossip_w),
+        global_avg=tree_agent_mean,
+        name=f"reference-dynamic/{topo.name}",
+        gossip_edges=int(topo.adj.sum()) // 2,
+        network=net,
+    )

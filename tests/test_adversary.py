@@ -20,12 +20,11 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st
 
-from conftest import make_logreg_problem
+from conftest import make_logreg_problem, reference_mixing
 from repro.core import (
     Experiment,
     ExperimentSpec,
     PiscoConfig,
-    dense_mixing,
     init_state,
     make_round_fn,
     make_sparse_topology,
@@ -269,14 +268,14 @@ def test_collusion_rows_agree():
 
 def test_clean_path_returns_base_object():
     for base in (
-        dense_mixing(make_topology("ring", 6)),
+        reference_mixing(make_topology("ring", 6)),
         sparse_mixing(make_sparse_topology("ring", 6)),
     ):
         assert make_adversarial_mixing(base, None, "mean", n_agents=6) is base
 
 
 def test_wrapper_preserves_accounting_metadata():
-    base = dense_mixing(make_topology("ring", 6))
+    base = sparse_mixing(make_sparse_topology("ring", 6))
     wrapped = make_adversarial_mixing(
         base, "signflip:f=0.2", "trimmed", n_agents=6
     )
@@ -286,7 +285,7 @@ def test_wrapper_preserves_accounting_metadata():
 
 
 def test_adversarial_network_unwraps_to_base():
-    base = dense_mixing(make_topology("ring", 6))
+    base = sparse_mixing(make_sparse_topology("ring", 6))
     wrapped = make_adversarial_mixing(base, "random:f=0.2", n_agents=6)
     assert isinstance(wrapped.network, AdversarialNetwork)
     assert unwrap_network(wrapped.network) is base.network
@@ -297,7 +296,7 @@ def test_wrapped_global_avg_applies_rule_to_corrupted_payloads():
     # end-to-end wiring pin: 2 flippers among 6 agents at value 1.0 — the
     # plain-mean wrapper sees {1,1,1,1,-1,-1} -> 1/3; trimmed recovers 1.0
     n = 6
-    base = dense_mixing(make_topology("full", n))
+    base = sparse_mixing(make_sparse_topology("full", n))
     tree = {"w": jnp.ones((n, 2), jnp.float32)}
     m_mean = make_adversarial_mixing(base, "signflip:f=0.2", "mean", n_agents=n)
     m_trim = make_adversarial_mixing(base, "signflip:f=0.2", "trimmed:f=0.2",
@@ -327,7 +326,7 @@ def _tracking_deviation(mixing, n=8, seed=0, rounds=3):
 
 
 def test_lemma1_survives_clean_breaks_under_corruption_and_robust_rules():
-    base = dense_mixing(make_topology("ring", 8))
+    base = sparse_mixing(make_sparse_topology("ring", 8))
     clean = _tracking_deviation(base)
     corrupted = _tracking_deviation(
         make_adversarial_mixing(base, "signflip:f=0.25", "mean", n_agents=8)

@@ -5,7 +5,13 @@ import numpy as np
 import pytest
 
 from conftest import make_logreg_problem
-from repro.core import PiscoConfig, dense_mixing, make_topology, replicate_params, run_training
+from repro.core import (
+    PiscoConfig,
+    make_sparse_topology,
+    replicate_params,
+    run_training,
+    sparse_mixing,
+)
 from repro.core.baselines import BASELINES
 
 
@@ -16,7 +22,7 @@ def test_baseline_converges(algo):
     n = 8
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(n_agents=n)
     cfg = PiscoConfig(n_agents=n, t_o=2, eta_l=0.15, eta_c=1.0, p=0.1, seed=0)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     hist = run_training(
         algo, loss_fn, x0, cfg, mixing, sampler_factory(cfg.t_o),
@@ -33,7 +39,7 @@ def test_gossip_pga_schedule_is_periodic():
     n = 4
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
     cfg = PiscoConfig(n_agents=n, t_o=1, eta_l=0.1, p=0.25, seed=0)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     hist = run_training(
         "gossip_pga", loss_fn, x0, cfg, mixing, sampler_factory(1), rounds=12
@@ -46,7 +52,7 @@ def test_fedavg_always_server():
     n = 4
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
     cfg = PiscoConfig(n_agents=n, t_o=2, eta_l=0.1, p=0.0, seed=0)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     hist = run_training(
         "fedavg", loss_fn, x0, cfg, mixing, sampler_factory(2), rounds=8
@@ -58,11 +64,10 @@ def test_fedavg_always_server():
 def test_scaffold_control_variates_average_to_server_variate():
     """After each SCAFFOLD round, c == mean_i(c_i) (server aggregation)."""
     from repro.core.baselines import make_scaffold_round_fn, scaffold_init
-    from repro.core.mixing import dense_mixing as dm
 
     n = 6
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
-    mixing = dm(make_topology("full", n))
+    mixing = sparse_mixing(make_sparse_topology("full", n))
     sampler = sampler_factory(2)
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     state = scaffold_init(loss_fn, x0, sampler(-1)[1])
@@ -79,7 +84,7 @@ def test_dsgt_matches_decentralized_structure():
 
     n = 6
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     sampler = sampler_factory(1)
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     state = dsgt_init(loss_fn, x0, sampler(-1)[1])

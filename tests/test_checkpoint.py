@@ -35,7 +35,7 @@ def test_trained_state_round_trip_bitwise(tmp_path):
     """Save -> restore of a real trained PISCO final state is bitwise exact
     (namedtuple state comes back as a plain tuple, same leaf order)."""
     from repro.core import (
-        PiscoConfig, dense_mixing, make_topology, replicate_params,
+        PiscoConfig, make_sparse_topology, replicate_params, sparse_mixing,
         run_training,
     )
 
@@ -44,7 +44,7 @@ def test_trained_state_round_trip_bitwise(tmp_path):
     cfg = PiscoConfig(n_agents=n, t_o=2, eta_l=0.1, eta_c=1.0, p=0.5, seed=0)
     hist = run_training(
         "pisco", loss_fn, replicate_params({"w": jnp.zeros(d)}, n), cfg,
-        dense_mixing(make_topology("ring", n)), sampler_factory(2), rounds=3,
+        sparse_mixing(make_sparse_topology("ring", n)), sampler_factory(2), rounds=3,
     )
     state = hist.final_state
     path = save_checkpoint(str(tmp_path), 3, state)

@@ -25,16 +25,17 @@ from repro.core import (
     TopKCompressor,
     IdentityCompressor,
     compress_mixing,
-    dense_mixing,
     init_state,
     init_compression_state,
     make_byte_model,
     make_compressor,
     make_round_fn,
+    make_sparse_topology,
     make_topology,
     message_bytes,
     replicate_params,
     run_training,
+    sparse_mixing,
 )
 from repro.kernels import quantize as Q
 from repro.kernels import ref as R
@@ -99,7 +100,7 @@ def test_error_feedback_residual_contracts():
     """The EF residual stays bounded (contraction): after many compressed
     gossip steps, ||residual|| never blows past the offered signal."""
     n, d = 8, 32
-    base = dense_mixing(make_topology("ring", n))
+    base = sparse_mixing(make_sparse_topology("ring", n))
     mix = compress_mixing(base, TopKCompressor(0.25), error_feedback=True)
     cg = mix.compression
     tree = {"w": jax.random.normal(jax.random.PRNGKey(2), (n, d), jnp.float32)}
@@ -126,7 +127,7 @@ def test_error_feedback_residual_contracts():
 @pytest.mark.parametrize("ef", [True, False])
 def test_compressed_gossip_preserves_agent_mean(spec, ef):
     n, d = 8, 33
-    base = dense_mixing(make_topology("ring", n))
+    base = sparse_mixing(make_sparse_topology("ring", n))
     mix = compress_mixing(base, make_compressor(spec), error_feedback=ef)
     tree = {"w": jax.random.normal(jax.random.PRNGKey(3), (n, d), jnp.float32)}
     out = mix.gossip(tree)  # stateless path
@@ -149,7 +150,7 @@ def test_lemma1_survives_compression(spec, t_o, seed):
     n = 8
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n, seed=seed)
     cfg = PiscoConfig(n_agents=n, t_o=t_o, eta_l=0.1, eta_c=0.9, p=0.5)
-    base = dense_mixing(make_topology("ring", n))
+    base = sparse_mixing(make_sparse_topology("ring", n))
     mix = compress_mixing(base, make_compressor(spec), error_feedback=True)
     sampler = sampler_factory(t_o, seed=seed)
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
@@ -218,7 +219,7 @@ def test_accountant_bytes_match_model_bernoulli(p):
     n = 8
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
     cfg = PiscoConfig(n_agents=n, t_o=1, eta_l=0.1, eta_c=1.0, p=p, seed=4)
-    base = dense_mixing(make_topology("ring", n))
+    base = sparse_mixing(make_sparse_topology("ring", n))
     mix = compress_mixing(base, StochasticQuantizer(bits=8))
     hist = run_training(
         "pisco", loss_fn, replicate_params({"w": jnp.zeros(d)}, n), cfg, mix,
@@ -250,7 +251,7 @@ def test_accountant_bytes_match_model_periodic():
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
     # gossip_pga derives H = round(1/p); p=0.25 -> server every 4th round
     cfg = PiscoConfig(n_agents=n, t_o=1, eta_l=0.1, eta_c=1.0, p=0.25, seed=0)
-    base = dense_mixing(make_topology("ring", n))
+    base = sparse_mixing(make_sparse_topology("ring", n))
     mix = compress_mixing(base, StochasticQuantizer(bits=8))
     rounds = 21
     hist = run_training(
@@ -279,7 +280,7 @@ def test_record_backward_compatible():
 def test_compressed_pisco_matches_uncompressed_at_4x_fewer_bytes():
     n = 8
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(n_agents=n)
-    base = dense_mixing(make_topology("ring", n))
+    base = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     cfg = PiscoConfig(n_agents=n, t_o=2, eta_l=0.15, eta_c=1.0, p=0.1, seed=0)
 
@@ -310,7 +311,7 @@ def test_gamma_auto_selection():
     """Contractive top-k gets the damped CHOCO step; quantizers run
     undamped; explicit gamma wins.  (Undamped top-k diverges under large
     local steps — see DESIGN.md §7.)"""
-    base = dense_mixing(make_topology("ring", 6))
+    base = sparse_mixing(make_sparse_topology("ring", 6))
     assert compress_mixing(base, TopKCompressor(0.1)).compression.gamma == 0.5
     assert compress_mixing(base, StochasticQuantizer(8)).compression.gamma == 1.0
     mix = compress_mixing(base, TopKCompressor(0.1), gamma=0.3)
@@ -324,6 +325,6 @@ def test_gamma_auto_selection():
 def test_disabled_compression_is_plain_mixing():
     """compress_mixing(identity) must return the base ops untouched, so the
     uncompressed path is bit-identical to the pre-compression code."""
-    base = dense_mixing(make_topology("ring", 4))
+    base = sparse_mixing(make_sparse_topology("ring", 4))
     assert compress_mixing(base, IdentityCompressor()) is base
     assert base.compression is None

@@ -1,6 +1,7 @@
 """Distributed-semantics tests: run a subprocess with 8 fake host devices and
 check that the collective (shard_map) mixers agree with the dense reference
-mixers, and that a sharded PISCO round equals the single-device one."""
+mixer (``conftest.reference_mix``), and that a sharded PISCO round equals the
+single-device one."""
 import os
 import subprocess
 import sys
@@ -23,10 +24,10 @@ _SCRIPT = textwrap.dedent(
         collective_global_mixing, collective_shift_mixing,
     )
     from repro.core.pisco import PiscoConfig, init_state, make_round_fn
-    from repro.core.mixing import dense_mixing, MixingOps
-    from repro.core.topology import make_topology
+    from repro.core.mixing import MixingOps
     from repro.launch.steps import gossip_matrix, mesh_gossip_shifts
-    from repro.utils.pytree import tree_agent_mean, tree_agent_mix
+    from repro.utils.pytree import tree_agent_mean
+    from conftest import reference_mix
 
     from repro.launch.mesh import make_mesh
 
@@ -55,7 +56,7 @@ _SCRIPT = textwrap.dedent(
     w = gossip_matrix(mesh, ("agents",), shifts)
     assert np.allclose(w.sum(0), 1) and np.allclose(w.sum(1), 1), "not doubly stochastic"
     out = jax.jit(ops.gossip)(sharded)
-    ref = tree_agent_mix(tree, w)
+    ref = reference_mix(tree, w)
     err = max(float(jnp.max(jnp.abs(out[k] - ref[k]))) for k in tree)
     assert err < 1e-6, f"ring gossip err {err}"
 
@@ -76,7 +77,7 @@ _SCRIPT = textwrap.dedent(
 
     state0 = init_state(loss_fn, x0, comm)
     dense_ops = MixingOps(
-        gossip=lambda t: tree_agent_mix(t, jnp.asarray(w, jnp.float32)),
+        gossip=lambda t: reference_mix(t, w),
         global_avg=tree_agent_mean,
     )
     fn_dense = jax.jit(make_round_fn(loss_fn, cfg, dense_ops, global_round=False))
@@ -106,7 +107,8 @@ _SCRIPT = textwrap.dedent(
 
 def test_collective_mixers_match_dense_in_subprocess():
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    here = os.path.dirname(__file__)
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(here, "..", "src"), here])
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT], env=env, capture_output=True, text=True,

@@ -5,14 +5,19 @@ import numpy as np
 import pytest
 
 from conftest import make_logreg_problem
-from repro.core import PiscoConfig, dense_mixing, make_topology, replicate_params, run_training
+from repro.core import (
+    PiscoConfig,
+    make_sparse_topology,
+    replicate_params,
+    run_training,
+    sparse_mixing,
+)
 from repro.core.mixing import compressed_mixing
 
 
 @pytest.mark.parametrize("bits", [8, 4])
 def test_compressed_gossip_quantizes(bits):
-    topo = make_topology("ring", 4)
-    base = dense_mixing(topo)
+    base = sparse_mixing(make_sparse_topology("ring", 4))
     comp = compressed_mixing(base, bits=bits)
     tree = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(4, 16)), jnp.float32)}
     out_c = comp.gossip(tree)
@@ -31,7 +36,7 @@ def test_pisco_converges_with_int8_gossip():
     n = 8
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(n_agents=n)
     cfg = PiscoConfig(n_agents=n, t_o=2, eta_l=0.15, eta_c=1.0, p=0.1, seed=0)
-    base = dense_mixing(make_topology("ring", n))
+    base = sparse_mixing(make_sparse_topology("ring", n))
     comp = compressed_mixing(base, bits=8)
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     hist = run_training(

@@ -14,25 +14,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import make_logreg_problem
-from repro.core import (
-    Experiment,
-    ExperimentSpec,
-    dense_mixing,
-    make_topology,
-    message_bytes,
-)
+from conftest import make_logreg_problem, reference_network_mixing
+from repro.core import Experiment, ExperimentSpec, message_bytes
 
 N_AGENTS = 5
 
 
-def _experiment(spec, n=N_AGENTS):
+def _experiment(spec, n=N_AGENTS, mixing=None):
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
     return Experiment(
         spec,
         loss_fn=loss_fn,
         params0={"w": jnp.zeros(d)},
         sampler_factory=lambda s: sampler_factory(s.config.t_o),
+        mixing=mixing,
     )
 
 
@@ -144,22 +139,22 @@ def test_joint_compression_dynamic_participation_bytes_hand_counted():
 
 def test_static_process_bytes_and_losses_match_legacy_dense_path():
     """network='static' runs through the dynamic machinery but must realize
-    the same matrices and the same per-round bytes as the legacy frozen-W
-    path (network=None)."""
+    the same weights and the same per-round bytes as the frozen-weight path
+    (network=None), and both must match the dense frozen-W reference."""
     base_kw = dict(
         algo="dsgt", n_agents=N_AGENTS, t_o=1, eta_l=0.1, p=0.3, seed=1,
         rounds=7, driver="scan", block_size=3,
     )
-    h_legacy = _experiment(ExperimentSpec.create(**base_kw)).run()
+    legacy = ExperimentSpec.create(**base_kw)
+    h_legacy = _experiment(legacy).run()
     h_static = _experiment(
         ExperimentSpec.create(network="static", **base_kw)
     ).run()
-    assert h_legacy.is_global == h_static.is_global
-    assert (
-        h_legacy.accountant.per_round_bytes
-        == h_static.accountant.per_round_bytes
-    )
-    np.testing.assert_allclose(h_legacy.loss, h_static.loss, rtol=1e-5, atol=1e-7)
+    h_dense = _experiment(legacy, mixing=reference_network_mixing(legacy)).run()
+    for h in (h_static, h_dense):
+        assert h_legacy.is_global == h.is_global
+        assert h_legacy.accountant.per_round_bytes == h.accountant.per_round_bytes
+        np.testing.assert_allclose(h_legacy.loss, h.loss, rtol=1e-5, atol=1e-7)
 
 
 # ---------------------------------------------------------------------------

@@ -524,7 +524,7 @@ def _fleet_block_hlo(local_opt=None, agents=16, rounds=4, batch=4):
 
     spec = ExperimentSpec.create(
         algo="pisco", n_agents=agents, t_o=2, eta_l=0.5, eta_c=1.0, p=0.1, seed=0,
-        topology="ring", sparse=True, driver="scan", block_size=rounds)
+        topology="ring", driver="scan", block_size=rounds)
     bound = get_algorithm("pisco").bind(
         mlp_loss, spec.config, spec.make_mixing(), local_opt=local_opt)
     sds = jax.ShapeDtypeStruct

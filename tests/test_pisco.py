@@ -17,12 +17,12 @@ from _hyp import given, settings, st
 from conftest import make_logreg_problem
 from repro.core import (
     PiscoConfig,
-    dense_mixing,
     init_state,
     make_round_fn,
-    make_topology,
+    make_sparse_topology,
     replicate_params,
     run_training,
+    sparse_mixing,
 )
 
 
@@ -48,7 +48,7 @@ def test_lemma1_tracking_invariant(t_o, p_global, topo_name, seed):
     n = 8
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n, seed=seed)
     cfg = PiscoConfig(n_agents=n, t_o=t_o, eta_l=0.1, eta_c=0.9, p=0.5)
-    mixing = dense_mixing(make_topology(topo_name, n))
+    mixing = sparse_mixing(make_sparse_topology(topo_name, n))
     sampler = sampler_factory(t_o, seed=seed)
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     state = init_state(loss_fn, x0, sampler(-1)[1])
@@ -62,7 +62,7 @@ def test_federated_round_gives_exact_consensus():
     n = 6
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
     cfg = PiscoConfig(n_agents=n, t_o=2, eta_l=0.1, eta_c=1.0, p=1.0)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     sampler = sampler_factory(2)
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     state = init_state(loss_fn, x0, sampler(-1)[1])
@@ -77,7 +77,7 @@ def test_pisco_converges_on_logreg():
     n = 8
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(n_agents=n)
     cfg = PiscoConfig(n_agents=n, t_o=4, eta_l=0.2, eta_c=1.0, p=0.1, seed=0)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     hist = run_training(
         "pisco", loss_fn, x0, cfg, mixing, sampler_factory(cfg.t_o),
@@ -93,7 +93,7 @@ def test_local_updates_accelerate():
     """Corollary 1's T_o speedup, measured in communication rounds."""
     n = 8
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(n_agents=n)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     rounds_needed = {}
     for t_o in (1, 8):
@@ -116,7 +116,7 @@ def test_server_rescues_disconnected_graph():
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(
         n_agents=n, heterogeneous=True
     )
-    mixing = dense_mixing(make_topology("disconnected", n, n_components=2))
+    mixing = sparse_mixing(make_sparse_topology("disconnected", n, n_components=2))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     results = {}
     for p in (0.0, 0.2):

@@ -12,13 +12,13 @@ from repro.core import (
     Experiment,
     ExperimentSpec,
     PiscoConfig,
-    dense_mixing,
     get_algorithm,
-    make_topology,
+    make_sparse_topology,
     register_algorithm,
     registered_algorithms,
     replicate_params,
     run_training,
+    sparse_mixing,
     unregister_algorithm,
 )
 from repro.core.schedule import PeriodicSchedule
@@ -27,7 +27,7 @@ from repro.data import FederatedDataset, RoundSampler
 
 def _problem(n=6, t_o=2):
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(n_agents=n)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     return loss_fn, full_grad_sq, sampler_factory, d, mixing, x0
 

@@ -202,7 +202,7 @@ def test_synthetic_fleet_is_lossless_topk(base):
 def test_from_history_and_checkpoint_round_trip(bundle, tmp_path):
     from repro.checkpoint import save_checkpoint
     from repro.core import (
-        PiscoConfig, dense_mixing, make_topology, replicate_params,
+        PiscoConfig, make_sparse_topology, replicate_params, sparse_mixing,
         run_training,
     )
 
@@ -219,7 +219,7 @@ def test_from_history_and_checkpoint_round_trip(bundle, tmp_path):
     cfg = PiscoConfig(n_agents=n, t_o=1, eta_l=0.05, eta_c=1.0, p=0.5, seed=0)
     x0 = replicate_params(bundle.init(jax.random.PRNGKey(1)), n)
     hist = run_training(
-        "pisco", bundle.loss, x0, cfg, dense_mixing(make_topology("ring", n)),
+        "pisco", bundle.loss, x0, cfg, sparse_mixing(make_sparse_topology("ring", n)),
         sampler, rounds=2,
     )
     stacked = jax.tree.map(np.asarray, hist.agent_params())

@@ -1,11 +1,13 @@
-"""Conformance suite for the sparse-network substrate.
+"""Conformance suite for edge-list gossip, the one gossip operand.
 
-The edge-list/CSR gossip path must be indistinguishable from the dense
-matrix path everywhere they overlap: same Metropolis weights, same training
-trajectories for every registered protocol under both drivers, same realized
-byte charges, and the same Lemma-1 mean-tracking invariant under compression.
-Property tests run through the optional-hypothesis shim (``tests/_hyp.py``),
-so they degrade to deterministic fixed examples when hypothesis is absent.
+Every mixer gossips from the edge list; it must be indistinguishable from
+the dense ``W @ x`` reference (``conftest.reference_mixing``) wherever a
+dense W can be built: the same Metropolis weights, every named topology,
+the same training trajectories for every registered protocol under both
+drivers, the same realized byte charges, and the same Lemma-1
+mean-tracking invariant under compression.  Property tests run through the
+optional-hypothesis shim (``tests/_hyp.py``), so they degrade to
+deterministic fixed examples when hypothesis is absent.
 """
 import json
 import re
@@ -16,12 +18,15 @@ import numpy as np
 import pytest
 
 from _hyp import given, settings, st
-from conftest import make_logreg_problem
+from conftest import (
+    make_logreg_problem,
+    reference_mixing,
+    reference_network_mixing,
+)
 from repro.core import (
     Experiment,
     ExperimentSpec,
     PiscoConfig,
-    dense_mixing,
     dynamic_sparse_mixing,
     is_doubly_stochastic,
     make_sparse_topology,
@@ -32,28 +37,22 @@ from repro.core import (
     replicate_params,
     run_training,
     sparse_mixing,
-    use_sparse_topology,
 )
 from repro.core.mixing import sparse_gossip
-from repro.core.topology import (
-    SPARSE_AUTO_MIN_AGENTS,
-    metropolis_weights,
-    sparse_topology_from_edges,
-)
-from repro.kernels import sparse_compressed_mix, sparse_mix, topology_edge_arrays
-from repro.kernels.ref import sparse_compressed_mix_ref, sparse_mix_ref
+from repro.core.topology import metropolis_weights, sparse_topology_from_edges
 from repro.utils.pytree import tree_agent_mix_sparse
 
 N_AGENTS = 5
 
 
-def _experiment(spec, n=N_AGENTS):
+def _experiment(spec, n=N_AGENTS, mixing=None):
     loss_fn, _, sampler_factory, d = make_logreg_problem(n_agents=n)
     return Experiment(
         spec,
         loss_fn=loss_fn,
         params0={"w": jnp.zeros(d)},
         sampler_factory=lambda s: sampler_factory(s.config.t_o),
+        mixing=mixing,
     )
 
 
@@ -162,6 +161,45 @@ def test_sparse_gossip_form_follows_the_edge_list(name, n, form):
     assert dynamic_sparse_mixing(process).form == form
 
 
+_NAMED = ["ring", "path", "star", "full", "erdos_renyi", "disconnected", "torus",
+          "random_regular"]
+
+
+@pytest.mark.parametrize("n", [1, 2, 9])
+@pytest.mark.parametrize("name", _NAMED)
+def test_every_named_topology_mixes_as_its_w(name, n):
+    """Every graph ``make_topology`` names, at every small size, gossips
+    through the spec's edge-list mixer exactly as its dense W."""
+    mixing = ExperimentSpec.create(n_agents=n, topology=name).make_mixing()
+    assert mixing.form is not None
+    x = np.random.default_rng(n).normal(size=(n, 3, 2)).astype(np.float32)
+    out = jax.jit(mixing.gossip)({"a": jnp.asarray(x)})["a"]
+    ref = np.tensordot(make_topology(name, n).w, x.astype(np.float64), axes=1)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("via", ["create", "from_dict"])
+@pytest.mark.parametrize("flag", [True, False, None])
+def test_spec_accepts_and_drops_the_sparse_key(flag, via):
+    """The retired ``sparse`` key (older callers and JSON payloads) is
+    accepted and dropped: whatever it held, the same edge-list mixer."""
+    kw = dict(algo="pisco", n_agents=6, p=0.2, network="bernoulli:0.3", rounds=3)
+    plain = ExperimentSpec.create(**kw)
+    if via == "create":
+        spec = ExperimentSpec.create(sparse=flag, **kw)
+    else:
+        spec = ExperimentSpec.from_dict(dict(plain.to_dict(), sparse=flag))
+    assert spec == plain and "sparse" not in spec.to_dict()
+    got, want = spec.make_mixing(), plain.make_mixing()
+    assert (got.name, got.form, got.gossip_edges) == (want.name, want.form, want.gossip_edges)
+    x = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(6, 4)), jnp.float32)}
+    ops = jax.tree.map(lambda a: a[0], got.network.draw_block(2, 3)[0])
+    got.network.slot.set(ops, None)
+    want.network.slot.set(ops, None)
+    np.testing.assert_array_equal(np.asarray(got.gossip(x)["w"]),
+                                  np.asarray(want.gossip(x)["w"]))
+
+
 def test_ring_gossip_lowers_without_gather_or_scatter():
     gossip = sparse_mixing(make_sparse_topology("ring", 64)).gossip
     x = {"w": jnp.zeros((64, 7, 3)), "b": jnp.zeros((64, 3))}
@@ -229,7 +267,7 @@ def test_doubly_stochastic_tolerance_scales_with_n():
 
 
 # ---------------------------------------------------------------------------
-# Full-protocol parity: dense path vs sparse path, both drivers
+# Full-protocol parity: edge-list mixers vs the dense reference, both drivers
 # ---------------------------------------------------------------------------
 
 
@@ -248,7 +286,7 @@ def test_sparse_training_matches_dense_all_protocols(algo):
         )
 
     for driver in ("scan", "loop"):
-        hd = run(dense_mixing(make_topology("ring", n)), driver)
+        hd = run(reference_mixing(make_topology("ring", n)), driver)
         hs = run(sparse_mixing(make_sparse_topology("ring", n)), driver)
         np.testing.assert_allclose(hd.loss, hs.loss, rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(
@@ -269,8 +307,8 @@ def test_sparse_experiment_spec_matches_dense(network):
         algo="pisco", n_agents=N_AGENTS, t_o=2, eta_l=0.1, p=0.3, seed=2,
         network=network, rounds=6, driver="scan", block_size=3,
     )
-    hd = _experiment(spec.replace(sparse=False)).run()
-    hs = _experiment(spec.replace(sparse=True)).run()
+    hd = _experiment(spec, mixing=reference_network_mixing(spec)).run()
+    hs = _experiment(spec).run()
     np.testing.assert_allclose(hd.loss, hs.loss, rtol=1e-5, atol=1e-6)
     assert hd.accountant.per_round_bytes == hs.accountant.per_round_bytes
 
@@ -286,7 +324,7 @@ def test_gt_invariant_on_sparse_path(network, compression):
     spec = ExperimentSpec.create(
         algo="pisco", n_agents=N_AGENTS, t_o=2, eta_l=0.1, p=0.3, seed=2,
         network=network, participation=0.6, compression=compression,
-        sparse=True, rounds=8, eval_every=4, driver="scan", block_size=3,
+        rounds=8, eval_every=4, driver="scan", block_size=3,
     )
     hist = _experiment(spec).run()
     state = hist.final_state
@@ -298,26 +336,25 @@ def test_gt_invariant_on_sparse_path(network, compression):
 
 
 # ---------------------------------------------------------------------------
-# Byte accounting: sparse edges priced identically to dense
+# Byte accounting: edge-list operands priced identically to dense W_k
 # ---------------------------------------------------------------------------
 
 
 def test_sparse_realized_gossip_bytes_match_hand_count():
     """roundrobin:2 on a 4-ring realizes 2 of 4 base edges per round; the
-    sparse accountant must charge the same 2 mixes x 4 directed messages
-    as the dense path — the wire does not care about the W representation."""
+    accountant must charge the same 2 mixes x 4 directed messages as the
+    dense reference — the wire does not care about the W representation."""
     n, rounds = 4, 4
     spec = ExperimentSpec.create(
         algo="pisco", n_agents=n, t_o=1, eta_l=0.1, p=0.0, seed=0,
-        network="roundrobin:2", sparse=True, rounds=rounds,
-        driver="scan", block_size=2,
+        network="roundrobin:2", rounds=rounds, driver="scan", block_size=2,
     )
     hist = _experiment(spec, n=n).run()
     msg = 16 * 4  # one fp32 message of the d=16 problem
     per_round = 2 * (2 * 2) * msg  # 2 mixes x (2 realized edges x 2 dirs)
     assert hist.accountant.per_round_bytes == [per_round] * rounds
     assert hist.accountant.agent_to_agent_bytes == rounds * per_round
-    h_dense = _experiment(spec.replace(sparse=False), n=n).run()
+    h_dense = _experiment(spec, n=n, mixing=reference_network_mixing(spec)).run()
     assert h_dense.accountant.per_round_bytes == \
         hist.accountant.per_round_bytes
 
@@ -354,63 +391,15 @@ def test_cohort_process_edges_are_seed_incident():
 def test_spec_json_round_trip_and_legacy_payload():
     spec = ExperimentSpec.create(
         algo="pisco", n_agents=2048, t_o=2, eta_l=0.1, p=0.1,
-        sparse=True, cohort=0.25, rounds=4,
+        cohort=0.25, rounds=4,
     )
     assert ExperimentSpec.from_json(spec.to_json()) == spec
-    # a pre-sparse-era payload (no sparse/cohort keys) loads with defaults
+    # a pre-cohort-era payload (no cohort key) loads with defaults
     legacy = json.loads(spec.to_json())
-    del legacy["sparse"], legacy["cohort"]
+    del legacy["cohort"]
     old = ExperimentSpec.from_dict(legacy)
-    assert old.sparse is None and old.cohort is None
+    assert old.cohort is None
     assert old.effective_network == old.network
-
-
-def test_auto_sparse_threshold():
-    assert not use_sparse_topology(None, SPARSE_AUTO_MIN_AGENTS)
-    assert use_sparse_topology(None, SPARSE_AUTO_MIN_AGENTS + 1)
-    assert use_sparse_topology(True, 2)
-    assert not use_sparse_topology(False, 10**6)
-
-
-# ---------------------------------------------------------------------------
-# Pallas sparse-mix kernels vs oracles (interpret mode)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("name,n,d", [("ring", 8, 16), ("star", 12, 130), ("torus", 16, 64)])
-def test_sparse_mix_kernel_matches_ref_and_dense(name, n, d):
-    topo = make_sparse_topology(name, n)
-    senders, receivers, edge_w = topology_edge_arrays(topo)
-    self_w = topo.self_weight.astype(np.float32)
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
-    out = sparse_mix(x, senders, receivers, edge_w, self_w, interpret=True)
-    ref = sparse_mix_ref(x, senders, receivers, edge_w, self_w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6)
-    dense = topo.dense_w().astype(np.float32) @ np.asarray(x)
-    np.testing.assert_allclose(np.asarray(out), dense, rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("bits", [4, 8])
-def test_sparse_compressed_mix_kernel_matches_ref(bits):
-    topo = make_sparse_topology("ring", 10)
-    senders, receivers, edge_w = topology_edge_arrays(topo)
-    self_w = topo.self_weight.astype(np.float32)
-    rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.normal(size=(10, 40)).astype(np.float32))
-    out = sparse_compressed_mix(
-        x, senders, receivers, edge_w, self_w, bits=bits, gamma=0.7,
-        interpret=True,
-    )
-    ref = sparse_compressed_mix_ref(
-        x, senders, receivers, edge_w, self_w, bits, gamma=0.7
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
-    # mean preservation: compressed sparse gossip is still difference-form
-    np.testing.assert_allclose(
-        np.asarray(out).mean(axis=0), np.asarray(x).mean(axis=0),
-        rtol=1e-4, atol=1e-5,
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -419,7 +408,7 @@ def test_sparse_compressed_mix_kernel_matches_ref(bits):
 
 
 def test_large_n_sparse_smoke():
-    """n=4096 sparse training — a size the dense path cannot represent
+    """n=4096 edge-list training — a size a dense W cannot represent
     without a 67 MB mixing matrix per operand.  Deliberately NOT marked
     slow: it pins that large-n stays cheap enough for the CI fast lane."""
     n, d, rounds = 4096, 4, 3

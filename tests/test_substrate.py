@@ -146,16 +146,29 @@ def test_checkpoint_pisco_state(tmp_path):
 @given(seed=st.integers(0, 50))
 @settings(max_examples=15, deadline=None)
 def test_tree_agent_mix_matches_matmul(seed):
-    from repro.core.topology import make_topology
-    from repro.utils.pytree import tree_agent_mean, tree_agent_mix
+    """Both edge-list gossip primitives (gather + segment_sum, and the
+    weighted shifts the ring takes) against W @ x."""
+    from repro.core.mixing import sparse_mixing
+    from repro.core.topology import make_sparse_topology
+    from repro.utils.pytree import tree_agent_mean, tree_agent_mix_sparse
 
     rng = np.random.default_rng(seed)
     n = 6
-    topo = make_topology("ring", n)
+    topo = make_sparse_topology("ring", n)
+    w = topo.dense_w()
     tree = {"a": jnp.asarray(rng.normal(size=(n, 4))), "b": jnp.asarray(rng.normal(size=(n, 2, 3)))}
-    mixed = tree_agent_mix(tree, topo.w)
-    ref_a = topo.w @ np.asarray(tree["a"])  # symmetric W: X W == W X row-wise
-    np.testing.assert_allclose(np.asarray(mixed["a"]), ref_a, atol=1e-5)
+    e = topo.edges
+    senders = jnp.asarray(np.concatenate([e[:, 0], e[:, 1]]))
+    receivers = jnp.asarray(np.concatenate([e[:, 1], e[:, 0]]))
+    edge_w = jnp.asarray(np.concatenate([topo.edge_weight] * 2), jnp.float32)
+    self_w = jnp.asarray(topo.self_weight, jnp.float32)
+    for mixed in (
+        tree_agent_mix_sparse(tree, senders, receivers, edge_w, self_w, n),
+        sparse_mixing(topo).gossip(tree),
+    ):
+        for k in tree:
+            ref = np.tensordot(w, np.asarray(tree[k]), axes=1)
+            np.testing.assert_allclose(np.asarray(mixed[k]), ref, atol=1e-5)
     avg = tree_agent_mean(tree)
     np.testing.assert_allclose(
         np.asarray(avg["a"]), np.tile(np.asarray(tree["a"]).mean(0, keepdims=True), (n, 1)),

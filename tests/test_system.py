@@ -13,10 +13,10 @@ import pytest
 from conftest import make_logreg_problem
 from repro.core import (
     PiscoConfig,
-    dense_mixing,
-    make_topology,
+    make_sparse_topology,
     replicate_params,
     run_training,
+    sparse_mixing,
 )
 from repro.utils.compile_cache import enable_compile_cache
 
@@ -112,7 +112,7 @@ def test_small_p_approaches_full_server_performance():
     """Fig. 5 phenomenon: p=0.1 performs close to p=1 in rounds-to-threshold."""
     n = 8
     loss_fn, full_grad_sq, sampler_factory, d = make_logreg_problem(n_agents=n)
-    mixing = dense_mixing(make_topology("ring", n))
+    mixing = sparse_mixing(make_sparse_topology("ring", n))
     x0 = replicate_params({"w": jnp.zeros(d)}, n)
     rounds = {}
     for p in (0.0, 0.1, 1.0):

@@ -11,6 +11,7 @@ from repro.core.topology import (
     TOPOLOGY_PROCESSES,
     Topology,
     best_constant_weights,
+    edge_list,
     erdos_renyi_graph,
     expected_mixing_rate,
     global_matrix,
@@ -24,6 +25,7 @@ from repro.core.topology import (
     second_singular_value,
     torus_graph,
 )
+from repro.utils.pytree import tree_agent_masked_mean
 
 
 @pytest.mark.parametrize("name", ["ring", "path", "star", "full"])
@@ -227,9 +229,13 @@ def test_process_draws_are_pure_functions_of_seed_and_round(spec, seed):
     base = make_topology("ring", 8)
     p1 = make_topology_process(spec, base, seed=seed)
     p2 = make_topology_process(spec, base, seed=seed)
-    ws, msgs = p1.draw_block(2, 7)
+    edge_w, self_w, msgs = p1.draw_sparse_block(2, 7)
+    e = edge_list(base.adj)
+    assert edge_w.shape == (5, len(e)) and self_w.shape == (5, 8)
     for i, k in enumerate(range(2, 7)):
-        np.testing.assert_allclose(ws[i], p2.weights_at(k).astype(np.float32))
+        w = np.diag(self_w[i])
+        w[e[:, 0], e[:, 1]] = w[e[:, 1], e[:, 0]] = edge_w[i]
+        np.testing.assert_allclose(w, p2.weights_at(k).astype(np.float32), atol=1e-7)
         assert msgs[i] == p2.messages_at(k)
 
 
@@ -273,10 +279,15 @@ def test_participation_matrix_is_doubly_stochastic_sampling(n, frac, k, seed):
 def test_participation_draws_are_deterministic_and_vary_by_round():
     p1 = ParticipationProcess(16, 0.25, seed=4)
     p2 = ParticipationProcess(16, 0.25, seed=4)
-    ss, counts = p1.draw_block(0, 8)
+    masks, counts = p1.draw_mask_block(0, 8)
     assert counts.tolist() == [4] * 8
     sets = set()
+    x = np.random.default_rng(0).normal(size=(16, 3)).astype(np.float32)
     for i in range(8):
-        np.testing.assert_allclose(ss[i], p2.server_matrix_at(i).astype(np.float32))
+        np.testing.assert_array_equal(np.flatnonzero(masks[i]), p2.participants_at(i))
+        np.testing.assert_allclose(
+            np.asarray(tree_agent_masked_mean(x, masks[i])),
+            p2.server_matrix_at(i) @ x, rtol=1e-6, atol=1e-6,
+        )
         sets.add(tuple(p2.participants_at(i).tolist()))
     assert len(sets) > 1, "participation never resampled"

@@ -103,16 +103,16 @@ def test_training_block_donates_its_carry_on_v5e(one_chip):
     from repro.configs import get_config
     from repro.core.algorithms import get_algorithm
     from repro.core.driver import make_block_fn
-    from repro.core.mixing import make_network_mixing
+    from repro.core.mixing import make_sparse_network_mixing
     from repro.core.pisco import PiscoConfig
-    from repro.core.topology import make_topology
+    from repro.core.topology import make_sparse_topology
     from repro.models import get_bundle
 
     n_agents, t_o, batch, seq = 2, 2, 2, 1024
     cfg = dataclasses.replace(get_config("mamba2-370m"), n_layers=1)
     bundle = get_bundle(cfg)
     pcfg = PiscoConfig(n_agents=n_agents, t_o=t_o, eta_l=0.05, p=0.5)
-    mixing = make_network_mixing(make_topology("ring", n_agents), None, 1.0)
+    mixing = make_sparse_network_mixing(make_sparse_topology("ring", n_agents))
     bound = get_algorithm("pisco").bind(bundle.loss, pcfg, mixing)
 
     params = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
